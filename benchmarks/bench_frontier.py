@@ -36,8 +36,10 @@ before): per backend the compiled cold program's ``cost_analysis``
 bytes are PER-ROUND (XLA counts a while-loop body once; see
 ``launch/roofline.py``), so ``bytes_round * rounds / wall_time`` is the
 achieved HBM bandwidth, reported as ``gbps_*`` and ``roofline_pct_*``
-(fraction of the per-chip ``HBM_BW`` peak); batched rows multiply by
-the batch trip count (the slowest lane's rounds) instead.
+(fraction of the chip's HBM peak from ``launch/roofline.PEAKS``);
+batched rows multiply by the batch trip count (the slowest lane's
+rounds) instead.  Off a chip with published peaks (the CPU included)
+both read "not measured": a host wall time is no device number.
 
 Each invocation appends rows to ``experiments/bench/frontier.json`` so
 successive PRs accumulate a trajectory.
@@ -65,6 +67,20 @@ def _time(fn, reps: int) -> float:
     return best
 
 
+NOT_MEASURED = "not measured"
+
+
+def _hbm_peak() -> float | None:
+    """HBM peak of the chip this runs on, None off a known chip."""
+    import jax
+    from repro.launch.roofline import peaks
+
+    try:
+        return peaks(jax.devices()[0].device_kind)["hbm_bw"]
+    except ValueError:
+        return None
+
+
 def _achieved(solver, results, ms_per_solve) -> tuple[float, float]:
     """Achieved HBM bandwidth for one backend's cold solves.
 
@@ -75,8 +91,11 @@ def _achieved(solver, results, ms_per_solve) -> tuple[float, float]:
     percentage divides by the per-chip HBM peak.
     """
     import jax.numpy as jnp
-    from repro.launch.roofline import HBM_BW, cost_dict
+    from repro.launch.roofline import cost_dict
 
+    hbm_bw = _hbm_peak()
+    if hbm_bw is None:
+        return NOT_MEASURED, NOT_MEASURED
     g = solver.graph
     compiled = solver._jit_one.lower(
         g, solver.ell, solver.csr, jnp.int32(results[0].source),
@@ -85,7 +104,7 @@ def _achieved(solver, results, ms_per_solve) -> tuple[float, float]:
     rounds = float(np.mean([r.rounds for r in results]))
     secs = ms_per_solve / 1e3
     gbps = per_round * rounds / secs / 1e9 if secs > 0 else 0.0
-    return round(gbps, 2), round(100.0 * gbps * 1e9 / HBM_BW, 3)
+    return round(gbps, 2), round(100.0 * gbps * 1e9 / hbm_bw, 3)
 
 
 def _achieved_batch(solver, batch_result, ms_batch) -> tuple[float, float]:
@@ -93,8 +112,11 @@ def _achieved_batch(solver, batch_result, ms_batch) -> tuple[float, float]:
     vmapped dense) program's per-round bytes times the batch trip count
     (the slowest lane's rounds — finished lanes ride along frozen)."""
     import jax.numpy as jnp
-    from repro.launch.roofline import HBM_BW, cost_dict
+    from repro.launch.roofline import cost_dict
 
+    hbm_bw = _hbm_peak()
+    if hbm_bw is None:
+        return NOT_MEASURED, NOT_MEASURED
     g = solver.graph
     b = len(batch_result.sources)
     compiled = solver._jit_batch.lower(
@@ -105,7 +127,7 @@ def _achieved_batch(solver, batch_result, ms_batch) -> tuple[float, float]:
     trips = float(np.max(batch_result.rounds))
     secs = ms_batch / 1e3
     gbps = per_round * trips / secs / 1e9 if secs > 0 else 0.0
-    return round(gbps, 2), round(100.0 * gbps * 1e9 / HBM_BW, 3)
+    return round(gbps, 2), round(100.0 * gbps * 1e9 / hbm_bw, 3)
 
 
 def run(n: int = 2000, families=("chain", "grid", "gnp", "geometric"),

@@ -43,6 +43,8 @@ def main() -> None:
     ap.add_argument("--only", default=None)
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import (bench_batch, bench_dynamic, bench_fleet,
                             bench_frontier, bench_heap_ops, bench_kernels,
                             bench_optimality, bench_p2p, bench_rounds,

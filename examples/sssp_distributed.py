@@ -1,9 +1,10 @@
-"""Distributed SP4 on 8 (virtual) devices: edges sharded over a
-(data, model) mesh, vertex state replicated, pmin all-reduces per round
-— bitwise identical to the single-device engine.
+"""Distributed SP4 over every device: edges sharded over a (data, model)
+mesh built from the device count, vertex state replicated, pmin
+all-reduces per round — bitwise identical to the single-device engine.
 
-This launcher-style script sets its own device-count override (the
-library and tests never do).
+On the CPU this launcher-style script gives itself 8 virtual devices
+(the override touches only the host platform; the library and tests
+never set it); on a TPU host it uses the chips it finds.
 
   python examples/sssp_distributed.py --n 20000
 """
@@ -29,17 +30,17 @@ def main():
     import jax
     from jax.sharding import Mesh
     from repro.core import generators as gen
-    from repro.core.graph import HostGraph
+    from repro.core.graph import build_graph
     from repro.sssp import SP4_CONFIG, Solver
 
     print(f"devices: {len(jax.devices())}")
     n, src, dst, w = gen.gnp(args.n, avg_deg=args.deg, seed=0)
-    hg = HostGraph(n, src, dst, w)
-    g = hg.to_device()
-    print(f"graph n={n} e={hg.e}")
+    g = build_graph(n, src, dst, w)
+    print(f"graph n={n} e={g.e}")
 
-    mesh = Mesh(np.asarray(jax.devices()).reshape(2, 4),
-                ("data", "model"))
+    devs = np.asarray(jax.devices())
+    rows = 2 if devs.size % 2 == 0 else 1
+    mesh = Mesh(devs.reshape(rows, -1), ("data", "model"))
     sharded = Solver(g, SP4_CONFIG, backend="distributed",
                      mesh=mesh, axes=("data", "model"))
     t0 = time.time()
@@ -59,8 +60,8 @@ def main():
     reach = int(np.isfinite(np.asarray(D)).sum())
     print(f"rounds={res.rounds}  reachable={reach}/{n}")
     print(f"single-device {t_single*1e3:.0f} ms | "
-          f"8-device sharded {t_dist*1e3:.0f} ms "
-          f"(CPU collectives; TPU scaling comes from the dry-run)")
+          f"{devs.size}-device sharded {t_dist*1e3:.0f} ms "
+          f"on {devs.flat[0].platform} (first calls: compile included)")
     print("bitwise identical ✓")
 
 

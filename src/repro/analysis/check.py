@@ -92,7 +92,7 @@ def _mutant_route(kind: str) -> Route:
             x = jax.tree_util.tree_leaves(out)[0]
             return x.astype(jnp.float64)
 
-        with jax.experimental.enable_x64():
+        with jax.enable_x64(True):
             cj = jax.make_jaxpr(bad)(*argv)
     else:
         raise SystemExit(f"unknown mutation {kind!r} "

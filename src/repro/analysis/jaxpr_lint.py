@@ -16,6 +16,11 @@ route against the :mod:`repro.analysis.contracts` registry:
   * forbidden primitives absent (host callbacks anywhere, ``sort``
     inside the round body);
   * 32-bit dtype discipline (no f64/i64 values anywhere);
+  * no ``gather`` of a bool array anywhere in the program: on TPU v5e
+    (JAX 0.9) a vmapped bool gather inside the round loop miscompiled
+    — batched solves stopped after two rounds — and a bool gather alone
+    did not, so where the fault lies is not pinned down; masks ride on
+    value gathers (+inf for a masked source) or gather as int32;
   * a dense-pass budget: the number of gather/scatter-class eqns in the
     hot region that sweep a full edge-layout dimension.  This pins the
     per-round ``inWeight_nf``/C-propagation cost — a PR that adds a
@@ -128,6 +133,7 @@ def dense_pass_count(sites: list[PrimSite],
 class Violation:
     rule: str        # "require:cumsum" | "forbid:pure_callback" |
     #                  "dense_budget" | "dtype:float64" | "require_cond:…"
+    #                  | "gather:bool"
     detail: str
     waiver: Waiver | None = None
 
@@ -240,6 +246,14 @@ def lint_route(route: str, closed_jaxpr, *,
         b = spec.budget_for(route)
         if b is not None:
             budget = b if budget is None else min(budget, b)
+
+    bool_gathers = sum(1 for s in sites if s.prim == "gather"
+                       and "bool" in s.out_dtypes)
+    if applied and bool_gathers:
+        add("gather:bool",
+            f"{bool_gathers} bool gather(s) in the program — one "
+            "miscompiled under vmap on TPU v5e; gather the mask folded "
+            "into the values (+inf) or as int32")
 
     if budget is not None and passes > budget:
         add("dense_budget",

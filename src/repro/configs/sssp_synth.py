@@ -50,8 +50,6 @@ def build_cell(cfg: SSSPConfig, shape: str) -> Cell:
         e_loc = e_pad // n_shards
         max_rounds = info["max_rounds"]
 
-        from jax.experimental.shard_map import shard_map
-
         def body(src, dst, w, out_weight):
             zeros = jnp.zeros((n,), jnp.float32)
             lg = Graph(n=n, e=e, e_pad=e_loc, src=src, dst=dst, w=w,
@@ -62,10 +60,10 @@ def build_cell(cfg: SSSPConfig, shape: str) -> Cell:
                            prims=distributed_prims(lg, axes))
             return state.D, state.C, state.round
 
-        fn = shard_map(
+        fn = jax.shard_map(
             body, mesh=mesh,
             in_specs=(P(axes), P(axes), P(axes), P()),
-            out_specs=(P(), P(), P()), check_rep=False)
+            out_specs=(P(), P(), P()), check_vma=False)
         shapes = (jax.ShapeDtypeStruct((e_pad,), jnp.int32),
                   jax.ShapeDtypeStruct((e_pad,), jnp.int32),
                   jax.ShapeDtypeStruct((e_pad,), jnp.float32),

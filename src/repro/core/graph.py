@@ -16,6 +16,7 @@ Design (see DESIGN.md §2):
 from __future__ import annotations
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -338,13 +339,14 @@ def build_ell(n: int, src, dst, w, *, lane: int = 128, sublane: int = 8,
     n_pad = max(sublane, round_up(n, sublane))
     in_src = np.full((n_pad, deg_pad), n, np.int32)
     in_w = np.full((n_pad, deg_pad), np.inf, np.float32)
+    # stable sort by destination, then each edge's slot is its rank
+    # within its destination's run (input order among parallel edges)
     order = np.argsort(dst, kind="stable")
-    slot = np.zeros(n, np.int64)
-    for idx in order:
-        d = dst[idx]
-        in_src[d, slot[d]] = src[idx]
-        in_w[d, slot[d]] = w[idx]
-        slot[d] += 1
+    d = dst[order]
+    run_start = np.cumsum(in_deg) - in_deg
+    slot = np.arange(len(d)) - run_start[d]
+    in_src[d, slot] = src[order]
+    in_w[d, slot] = w[order]
     return EllGraph(n=n, n_pad=n_pad, deg_pad=deg_pad,
                     in_src=jnp.asarray(in_src), in_w=jnp.asarray(in_w))
 
@@ -354,7 +356,12 @@ def build_ell(n: int, src, dst, w, *, lane: int = 128, sublane: int = 8,
 # ---------------------------------------------------------------------------
 
 class HostGraph:
-    """Plain-python adjacency view (out- and in-lists) for reference algos."""
+    """Host COO edge arrays, with plain-python adjacency views (out- and
+    in-lists) for the reference algorithms.
+
+    The lists are built on first use: a graph that only travels to the
+    device (``to_device``/``to_ell``) never pays the per-edge Python loop.
+    """
 
     def __init__(self, n: int, src, dst, w):
         self.n = int(n)
@@ -363,11 +370,20 @@ class HostGraph:
         self.w = np.asarray(w, np.float64)
         self.e = len(self.src)
         assert (self.w > 0).all(), "strictly positive weights required"
-        self.out: list[list[tuple[int, float]]] = [[] for _ in range(self.n)]
-        self.inn: list[list[tuple[int, float]]] = [[] for _ in range(self.n)]
-        for s, d, ww in zip(self.src, self.dst, self.w):
-            self.out[s].append((int(d), float(ww)))
-            self.inn[d].append((int(s), float(ww)))
+
+    @functools.cached_property
+    def out(self) -> list[list[tuple[int, float]]]:
+        return self._adjacency(self.src, self.dst)
+
+    @functools.cached_property
+    def inn(self) -> list[list[tuple[int, float]]]:
+        return self._adjacency(self.dst, self.src)
+
+    def _adjacency(self, key, other) -> list[list[tuple[int, float]]]:
+        lists: list[list[tuple[int, float]]] = [[] for _ in range(self.n)]
+        for k, o, ww in zip(key.tolist(), other.tolist(), self.w.tolist()):
+            lists[k].append((o, ww))
+        return lists
 
     def to_device(self, **kw) -> Graph:
         return build_graph(self.n, self.src, self.dst, self.w, **kw)

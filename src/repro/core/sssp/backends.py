@@ -83,7 +83,7 @@ def _masked_min_local(x: jax.Array, mask: jax.Array) -> jax.Array:
     "backend.segment",
     routes=("segment.*",),
     require=("scatter-min",),
-    dense_budget={"segment.warm": 11, "segment.*": 8},
+    dense_budget={"segment.warm": 8, "segment.*": 6},
     notes="The default backend relaxes via jax.ops.segment_min over "
           "the dst-sorted edge list — the compiled program must "
           "contain the scatter-min lowering in the hot region, and a "
@@ -93,13 +93,16 @@ def segment_prims(g: Graph) -> Primitives:
     """Segment reductions over the dst-sorted edge list (the default)."""
 
     def relax(x, src_mask):
-        ok = g.gather_src(src_mask, fill=False)
-        cand = jnp.where(ok, g.gather_src(x) + g.w, INF)
-        return g.seg_min_at_dst(cand)
+        # The mask rides on the value gather: a masked source offers
+        # +inf, and +inf + w is +inf.  One f32 gather per edge, no bool
+        # gather: on TPU v5e a vmapped bool gather of this shape
+        # miscompiled inside the round loop (batched solves stopped
+        # after two rounds with wrong distances).
+        xm = jnp.where(src_mask, x, INF)
+        return g.seg_min_at_dst(g.gather_src(xm) + g.w)
 
     def in_weight_nf(nf_mask):
-        ok = g.gather_src(nf_mask, fill=False)
-        return g.seg_min_at_dst(jnp.where(ok, g.w, INF))
+        return relax(jnp.zeros(nf_mask.shape, jnp.float32), nf_mask)
 
     return Primitives(relax=relax, in_weight_nf=in_weight_nf,
                       masked_min=_masked_min_local)
@@ -109,7 +112,7 @@ def segment_prims(g: Graph) -> Primitives:
     "backend.ell",
     routes=("ell.*",),
     require=("gather", "reduce_min"),
-    dense_budget={"ell.warm": 8, "ell.*": 6},
+    dense_budget={"ell.warm": 4, "ell.*": 3},
     notes="The ELL backend is row-form: relax is a masked row-min over "
           "the padded in-neighbourhood (gather + reduce_min; no "
           "scatter at all), which is why its dense budget is the "
@@ -118,7 +121,7 @@ def segment_prims(g: Graph) -> Primitives:
     "backend.pallas",
     routes=("pallas.*",),
     require=("pallas_call",),
-    dense_budget=11,
+    dense_budget=8,
     notes="use_pallas=True must actually route through the Pallas "
           "kernels: the hot region must contain pallas_call eqns "
           "(interpret mode on CPU CI still lowers to pallas_call).")
@@ -151,8 +154,8 @@ def ell_prims(g: Graph, ell: EllGraph, use_pallas: bool) -> Primitives:
     "backend.frontier",
     routes=("frontier.*",),
     require=("cumsum", "scatter-min"),
-    dense_budget={"frontier.cold": 3, "frontier.targeted": 3,
-                  "frontier.batched": 3, "frontier.warm": 6},
+    dense_budget={"frontier.cold": 2, "frontier.targeted": 2,
+                  "frontier.batched": 2, "frontier.warm": 4},
     notes="The whole point of this backend is the compacted sparse "
           "relax: the program must contain the cumsum frontier "
           "compaction AND the scatter-min relax — on EVERY route, "
@@ -209,7 +212,7 @@ def frontier_prims(g: Graph, csr: CsrGraph, cap: int,
     "backend.distributed",
     routes=("distributed.*",),
     require=("scatter-min", "pmin"),
-    dense_budget={"distributed.warm": 11, "distributed.*": 8},
+    dense_budget={"distributed.warm": 8, "distributed.*": 6},
     notes="Shard-local segment relax + cross-shard pmin combine: both "
           "must survive compilation (a missing pmin means the combine "
           "was constant-folded away and shards silently diverge).")

@@ -151,7 +151,7 @@ class BidiResult:
     "bidi.pair_lanes",
     routes=("bidi.*",),
     require=("scatter-min",),
-    dense_budget={"bidi.warm": 11, "bidi.*": 8},
+    dense_budget={"bidi.warm": 8, "bidi.*": 6},
     notes="Forward and reverse searches run as TWO LANES of one "
           "vmapped segment-backend program (one dispatch per round "
           "pair, not two); the lanes share the round body, so the "

@@ -30,7 +30,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.core.graph import Graph, INF, round_up
 from repro.core.sssp.backends import distributed_prims
@@ -88,10 +87,10 @@ def make_sharded_solver(g: Graph, cfg: SSSPConfig = SP4_CONFIG,
             lambda s, t, c: _solve(lg, cfg, s, prims=prims, C0=c, target=t)
         )(sources, targets, C0)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(edge_spec, edge_spec, edge_spec) + (vert_spec,) * 4,
-        out_specs=vert_spec, check_rep=False)
+        out_specs=vert_spec, check_vma=False)
     jitted = jax.jit(fn)
 
     def solve_batch(sources: jax.Array, graph: Graph | None = None,
@@ -152,10 +151,10 @@ def make_sharded_warm(g: Graph, cfg: SSSPConfig = SP4_CONFIG,
                                              prims=prims)
         )(prev_D, prev_F, seeds, pure_inc)
 
-    sharded = shard_map(
+    sharded = jax.shard_map(
         body, mesh=mesh,
         in_specs=(edge_spec, edge_spec, edge_spec) + (vert_spec,) * 5,
-        out_specs=vert_spec, check_rep=False)
+        out_specs=vert_spec, check_vma=False)
 
     @jax.jit
     def warm(g_old: Graph, _ell, _csr, delta, prev_D, prev_F):
@@ -199,9 +198,9 @@ def lower_distributed(g: Graph, mesh: Mesh, source: int = 0,
         state = _solve(lg, cfg, source, prims=distributed_prims(lg, axes))
         return state.D, state.C, state.fixed, state.round
 
-    fn = shard_map(body, mesh=mesh,
+    fn = jax.shard_map(body, mesh=mesh,
                    in_specs=(edge_spec, edge_spec, edge_spec),
-                   out_specs=(vert_spec,) * 4, check_rep=False)
+                   out_specs=(vert_spec,) * 4, check_vma=False)
     shapes = (jax.ShapeDtypeStruct((g.e_pad,), jnp.int32),
               jax.ShapeDtypeStruct((g.e_pad,), jnp.int32),
               jax.ShapeDtypeStruct((g.e_pad,), jnp.float32))

@@ -292,7 +292,8 @@ def _warm_seed_mask(g: Graph, taint: jax.Array, fixed: jax.Array,
     if dec_src is None:
         return fixed & (D < INF)
     # in-boundary of the cone: fixed tails of edges into taint
-    at = jnp.where(g.gather_dst(taint, fill=False), g.src, g.n)
+    at = jnp.where(g.gather_dst(taint.astype(jnp.int32), fill=0) > 0,
+                   g.src, g.n)
     bnd = jnp.zeros((g.n,), bool).at[at].set(True, mode="drop")
     return (bnd | dec_src) & fixed & (D < INF)
 
@@ -503,8 +504,8 @@ def _round(g: Graph, cfg: SSSPConfig, state: SSSPState,
         # buffer on sparse rounds, the whole padded edge list on dense
         # fallback rounds.
         u = jnp.minimum(state.f_idx, g.n - 1)
-        live = (state.f_idx < g.n) & relax_src[u]
-        sparse_edges = jnp.sum(jnp.where(live, g.out_deg[u], 0),
+        deg = jnp.where(relax_src, g.out_deg, 0)[u]
+        sparse_edges = jnp.sum(jnp.where(state.f_idx < g.n, deg, 0),
                                dtype=jnp.int32)
         edges = edges + jnp.where(overflow, jnp.int32(g.e_pad),
                                   sparse_edges)
@@ -694,8 +695,8 @@ def _round_shared(g: Graph, cfg: SSSPConfig, state: SSSPState,
             lambda: jax.vmap(prims.relax)(D, relax_src),
             lambda: prims.relax_frontier_b(D, f_idx, relax_src))
     u = jnp.minimum(f_idx, g.n - 1)
-    live = (f_idx < g.n)[None, :] & relax_src[:, u]
-    sparse_edges = jnp.sum(jnp.where(live, g.out_deg[u][None, :], 0),
+    deg = jnp.where(relax_src, g.out_deg[None, :], 0)[:, u]
+    sparse_edges = jnp.sum(jnp.where((f_idx < g.n)[None, :], deg, 0),
                            axis=1, dtype=jnp.int32)
     edges = state.edges + jnp.where(overflow, jnp.int32(g.e_pad),
                                     sparse_edges)
@@ -780,7 +781,8 @@ def _round_shared(g: Graph, cfg: SSSPConfig, state: SSSPState,
                 cin = prims.in_min_at(C_pre, tgts, None)  # all sources
                 tc = jnp.minimum(tgts, g.n - 1)
                 cur = C_pre[:, tc]
-                upd = ~fixed1[:, tc] & (tgts < g.n)[None]
+                nf = jnp.where(fixed1, 0, 1)[:, tc] > 0   # no bool gather
+                upd = nf & (tgts < g.n)[None]
                 val = jnp.where(upd, jnp.maximum(cur, cin), cur)
                 return cn.at[:, tgts].set(val, mode="drop")
 
@@ -871,9 +873,8 @@ def _frontier_fixpoint(g: Graph, cfg: SSSPConfig, prims,
               & (st.round < max_rounds))
         if targets is not None:
             t = jnp.maximum(targets, 0)
-            lanes = jnp.arange(B)
-            t_done = ((targets >= 0) & st.fixed[lanes, t]
-                      & st.explored[lanes, t])
+            settled = jnp.where(st.fixed & st.explored, 1, 0)  # no bool
+            t_done = (targets >= 0) & (settled[jnp.arange(B), t] > 0)
             go = go & ~t_done
         return go
 
@@ -969,7 +970,10 @@ def _cond(state: SSSPState, max_rounds: int, target=None):
     go = (jnp.any(active) | jnp.any(pending)) & (state.round < max_rounds)
     if target is not None:
         t = jnp.maximum(target, 0)           # clamp sentinel for the gather
-        t_done = (target >= 0) & state.fixed[t] & state.explored[t]
+        # an int32 gather: bool gathers under vmap miscompiled on TPU
+        # v5e (backends.segment_prims)
+        settled = jnp.where(state.fixed & state.explored, 1, 0)
+        t_done = (target >= 0) & (settled[t] > 0)
         go = go & ~t_done
     return go
 

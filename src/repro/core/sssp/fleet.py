@@ -297,7 +297,7 @@ class FleetBatchResult:
     "fleet.lockstep",
     routes=("fleet.*",),
     require=("scatter-min",),
-    dense_budget={"fleet.warm": 11, "fleet.*": 8},
+    dense_budget={"fleet.warm": 8, "fleet.*": 6},
     notes="F graphs solve in ONE dispatch: the round body is vmapped "
           "over the fleet axis on the shape-unified edge layout.  The "
           "per-member program is the segment backend, so the segment "
@@ -307,7 +307,7 @@ class FleetBatchResult:
     "fleet.frontier",
     routes=("fleet_frontier.*",),
     require=("cumsum", "scatter-min"),
-    dense_budget={"fleet_frontier.warm": 12, "fleet_frontier.*": 6},
+    dense_budget={"fleet_frontier.warm": 8, "fleet_frontier.*": 4},
     notes="backend='frontier' python-unrolls the members through the "
           "shared-batch-frontier round body — the compiled program "
           "must contain each member's cumsum union compaction and "

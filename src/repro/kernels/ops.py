@@ -1,10 +1,11 @@
 """jit'd dispatch wrappers: Pallas kernel vs pure-jnp reference.
 
-On this CPU container the kernels always run in interpret mode (the
-kernel body executes in Python op-by-op) — correct but slow, so the
-*default* execution path everywhere is the jnp reference, and the Pallas
-path is selected explicitly (tests, TPU deployments via
-``REPRO_USE_PALLAS=1`` or config flags).
+The jnp reference is the default path everywhere; the Pallas path is
+selected explicitly (``use_pallas=True``, the ``pallas`` backend, or
+``REPRO_USE_PALLAS=1``).  Every kernel call below goes through
+:func:`_interpret`: on a TPU the kernels compile with Mosaic, and on the
+CPU backend (tests, CI) they run in Pallas interpret mode, where the
+kernel body executes op by op — correct but slow.
 """
 from __future__ import annotations
 
@@ -23,6 +24,11 @@ from repro.kernels.relax import relax_ell as _relax_pallas
 from repro.kernels.segment_min import masked_min as _masked_min_pallas
 from repro.kernels.cin import cin_layer as _cin_pallas
 from repro.kernels.flash_attn import flash_attention as _flash_pallas
+
+
+def _interpret() -> bool:
+    """Interpret mode only on the CPU backend; compiled kernels elsewhere."""
+    return jax.default_backend() == "cpu"
 
 
 def _use_pallas(flag: bool | None) -> bool:
@@ -48,12 +54,15 @@ def relax_ell(D: jax.Array, ell: EllGraph, src_mask: jax.Array,
     """
     idx = jnp.minimum(ell.in_src, ell.n - 1)   # clamp: pure gathers below
     in_range = ell.in_src < ell.n
-    d_src = D[idx]                     # [n_pad, deg_pad] XLA gather
-    mask = in_range & src_mask[idx]
+    # [n_pad, deg_pad] XLA gather; the source mask rides on the values
+    # (a masked source offers +inf) — no bool gather, see
+    # backends.segment_prims
+    d_src = jnp.where(src_mask, D, jnp.inf)[idx]
     if _use_pallas(use_pallas):
-        out = _relax_pallas(d_src, ell.in_w, mask)
+        out = _relax_pallas(d_src, ell.in_w, in_range,
+                            interpret=_interpret())
     else:
-        out = ref.relax_ell_ref(d_src, ell.in_w, mask)
+        out = ref.relax_ell_ref(d_src, ell.in_w, in_range)
     return out[: ell.n]
 
 
@@ -77,16 +86,17 @@ def frontier_relax(x: jax.Array, csr: CsrGraph, f_idx: jax.Array,
     """
     n = csr.n
     u = jnp.minimum(f_idx, n - 1)              # clamp: pure gathers below
-    slot_ok = (f_idx < n) & src_mask[u]
+    xu = jnp.where(src_mask, x, jnp.inf)[u]    # masked sources offer +inf
     base = csr.indptr[u]                       # int32[cap]
     deg = csr.indptr[u + 1] - base
     j = jnp.arange(csr.max_out_deg, dtype=jnp.int32)[None, :]
-    cell_ok = slot_ok[:, None] & (j < deg[:, None])
+    cell_ok = (f_idx < n)[:, None] & (j < deg[:, None])
     epos = jnp.minimum(base[:, None] + j, csr.e_pad - 1)
     tgt = jnp.where(cell_ok, csr.dst[epos], n)      # n = dropped
-    cand = jnp.where(cell_ok, x[u][:, None] + csr.w[epos], jnp.inf)
+    cand = jnp.where(cell_ok, xu[:, None] + csr.w[epos], jnp.inf)
     if _use_pallas(use_pallas):
-        return _frontier_scatter_pallas(tgt, cand, n)
+        return _frontier_scatter_pallas(tgt, cand, n,
+                                        interpret=_interpret())
     return ref.frontier_scatter_min_ref(tgt, cand, n)
 
 
@@ -112,10 +122,11 @@ def frontier_relax_b(x: jax.Array, csr: CsrGraph, f_idx: jax.Array,
     epos = jnp.minimum(base[:, None] + j, csr.e_pad - 1)
     tgt = jnp.where(cell_ok, csr.dst[epos], n)      # SHARED [cap, max_out]
     w = csr.w[epos]
-    lane_ok = cell_ok[None] & src_mask[:, u][:, :, None]
-    cand = jnp.where(lane_ok, x[:, u][:, :, None] + w[None], jnp.inf)
+    xu = jnp.where(src_mask, x, jnp.inf)[:, u]    # masked sources: +inf
+    cand = jnp.where(cell_ok[None], xu[:, :, None] + w[None], jnp.inf)
     if _use_pallas(use_pallas):
-        return _frontier_scatter_batch_pallas(tgt, cand, n)
+        return _frontier_scatter_batch_pallas(tgt, cand, n,
+                                              interpret=_interpret())
     return ref.frontier_scatter_min_batch_ref(tgt, cand, n)
 
 
@@ -169,19 +180,16 @@ def in_min_at(g: Graph, csr: CsrGraph, x: jax.Array | None,
     uc = jnp.minimum(u_raw, n - 1)
     ok = cell & (u_raw < n)
     w = jnp.where(ok, g.w[epos], jnp.inf)      # [*T, max_in]
-    if x is None:
-        val = w[None]
-    else:
-        val = x[:, uc] + w[None]               # masked cells stay +inf
-    if src_mask is not None:
-        val = jnp.where(src_mask[:, uc] & ok[None], val, jnp.inf)
+    if src_mask is not None:                   # masked sources offer +inf
+        x = jnp.where(src_mask, 0.0 if x is None else x, jnp.inf)
+    val = w[None] if x is None else x[:, uc] + w[None]
     return jnp.min(val, axis=-1)
 
 
 def masked_min(x: jax.Array, mask: jax.Array,
                *, use_pallas: bool | None = None) -> jax.Array:
     if _use_pallas(use_pallas):
-        return _masked_min_pallas(x, mask)
+        return _masked_min_pallas(x, mask, interpret=_interpret())
     return ref.masked_min_ref(x, mask)
 
 
@@ -196,7 +204,8 @@ def cin_layer(x_k: jax.Array, x_0: jax.Array, w: jax.Array,
                 [x_k, jnp.zeros((pad,) + x_k.shape[1:], x_k.dtype)])
             x_0 = jnp.concatenate(
                 [x_0, jnp.zeros((pad,) + x_0.shape[1:], x_0.dtype)])
-        out = _cin_pallas(x_k, x_0, w, block_b=bb)
+        out = _cin_pallas(x_k, x_0, w, block_b=bb,
+                          interpret=_interpret())
         return out[:B]
     return ref.cin_layer_ref(x_k, x_0, w)
 
@@ -204,5 +213,6 @@ def cin_layer(x_k: jax.Array, x_0: jax.Array, w: jax.Array,
 def flash_attention(q, k, v, *, causal: bool = True,
                     use_pallas: bool | None = None):
     if _use_pallas(use_pallas):
-        return _flash_pallas(q, k, v, causal=causal)
+        return _flash_pallas(q, k, v, causal=causal,
+                             interpret=_interpret())
     return ref.flash_attention_ref(q, k, v, causal=causal)

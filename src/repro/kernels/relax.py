@@ -44,7 +44,7 @@ def _relax_kernel(d_src_ref, w_ref, mask_ref, out_ref):
 def relax_ell(d_src: jax.Array, w: jax.Array, mask: jax.Array,
               *, block_rows: int = DEFAULT_BLOCK_ROWS,
               block_cols: int = DEFAULT_BLOCK_COLS,
-              interpret: bool = True) -> jax.Array:
+              interpret: bool = False) -> jax.Array:
     """float32[n_pad, deg_pad] x3 -> float32[n_pad] row-min.
 
     Requires n_pad % block_rows == 0 and deg_pad % block_cols == 0 (the

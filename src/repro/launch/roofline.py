@@ -12,11 +12,12 @@ IMPORTANT calibration facts (verified empirically on this jax/XLA):
     unroll their layer loops in python — no correction needed.  The
     SSSP cells report PER-ROUND terms (round count is data-dependent).
 
-Terms per (arch x shape x mesh), seconds per step on TPU v5e:
+Terms per (arch x shape x mesh), seconds per step on the chip whose
+``device_kind`` keys :data:`PEAKS` (the dry-run targets a TPU v5e):
 
-  compute    = flops_per_chip / 197e12        bf16 MXU peak
-  memory     = bytes_per_chip / 819e9         HBM bandwidth
-  collective = coll_bytes_per_chip / 50e9     ICI link bandwidth
+  compute    = flops_per_chip / peak FLOP/s   (bf16 MXU peak)
+  memory     = bytes_per_chip / peak HBM bytes/s
+  collective = coll_bytes_per_chip / ICI bytes/s per link
 
 collective_bytes sums the OUTPUT shapes of all-gather / all-reduce /
 reduce-scatter / all-to-all / collective-permute in the post-SPMD HLO
@@ -27,9 +28,23 @@ from __future__ import annotations
 import dataclasses
 import re
 
-PEAK_FLOPS = 197e12       # TPU v5e bf16 / chip
-HBM_BW = 819e9            # bytes/s per chip
-ICI_BW = 50e9             # bytes/s per link
+# Published per-chip peaks, keyed by ``jax.Device.device_kind``.
+# TPU v5e: Google Cloud documentation, "TPU v5e" — 197 TFLOP/s bf16,
+# 819 GB/s HBM, 1,600 Gbit/s ICI per chip (4 links of 50 GB/s).
+PEAKS = {
+    "TPU v5 lite": dict(flops=197e12, hbm_bw=819e9, ici_bw=50e9),
+}
+DRYRUN_KIND = "TPU v5 lite"   # the chip the dry-run cells compile for
+
+
+def peaks(device_kind: str) -> dict:
+    """Peaks of one chip of ``device_kind``; an unknown kind (the CPU
+    included) raises — a device number needs a device with a source."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(f"no published peaks for device kind "
+                         f"{device_kind!r}; known: {sorted(PEAKS)}") from None
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,
@@ -94,15 +109,15 @@ class RooflineTerms:
 
     @property
     def t_compute(self) -> float:
-        return self.flops / PEAK_FLOPS
+        return self.flops / peaks(DRYRUN_KIND)["flops"]
 
     @property
     def t_memory(self) -> float:
-        return self.bytes_accessed / HBM_BW
+        return self.bytes_accessed / peaks(DRYRUN_KIND)["hbm_bw"]
 
     @property
     def t_collective(self) -> float:
-        return self.collective_bytes / ICI_BW
+        return self.collective_bytes / peaks(DRYRUN_KIND)["ici_bw"]
 
     @property
     def bottleneck(self) -> str:
@@ -126,8 +141,8 @@ class RooflineTerms:
         model's 6ND work achieves if the step runs at t_bound."""
         if not self.t_bound:
             return 0.0
-        return (self.model_flops / (self.n_chips * PEAK_FLOPS)) \
-            / self.t_bound
+        peak = peaks(DRYRUN_KIND)["flops"]
+        return (self.model_flops / (self.n_chips * peak)) / self.t_bound
 
     def to_dict(self) -> dict:
         return {

@@ -7,8 +7,9 @@ Generates a graph, stands up the continuous-batching
 :class:`~repro.runtime.sssp_service.SSSPService`, fires a synthetic
 query stream with a Zipf-ish repeated-source distribution (the
 realistic serving regime: popular origins dominate), and reports
-queries/sec, batch count, and cache hit rate.  ``--verify`` re-checks a
-sample of answers against the host Dijkstra reference.
+queries/sec, batch count, and cache hit rate, after a line naming the
+device it ran on.  ``--verify`` re-checks a sample of answers against
+scipy's compiled Dijkstra (``reference.scipy_dijkstra``).
 
 ``--deltas K`` interleaves K random weight deltas (``--delta-edges``
 edges each) between query waves — the dynamic-graph serving regime:
@@ -67,16 +68,22 @@ def main() -> None:
                          "tightness drops below this (needs --landmarks)")
     args = ap.parse_args()
 
+    import jax
     import numpy as np
     from repro.core import generators as gen
-    from repro.core.graph import HostGraph
+    from repro.core.graph import build_graph
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.runtime.sssp_service import Query, SSSPService
 
-    n, src, dst, w = gen.make(args.family, args.n, seed=args.seed)
-    hg = HostGraph(n, src, dst, w)
-    print(f"graph: {args.family} n={n} e={hg.e}  backend={args.backend}")
+    enable_compile_cache()
+    dev = jax.devices()[0]
+    print(f"device: {dev.platform} {dev.device_kind} "
+          f"x{len(jax.devices())}")
+    g = build_graph(*gen.make(args.family, args.n, seed=args.seed))
+    n = g.n
+    print(f"graph: {args.family} n={n} e={g.e}  backend={args.backend}")
 
-    service = SSSPService(hg.to_device(), backend=args.backend,
+    service = SSSPService(g, backend=args.backend,
                           batch=args.batch,
                           landmarks=args.landmarks or None,
                           planner=args.planner,
@@ -98,7 +105,7 @@ def main() -> None:
         final_wave = wave
         if args.deltas and i + per_wave < len(queries):
             from repro.sssp import random_delta
-            k = (max(1, hg.e // 100) if args.delta_edges is None
+            k = (max(1, g.e // 100) if args.delta_edges is None
                  else args.delta_edges)
             dstats = service.apply_delta(
                 random_delta(service.solver.graph, k,
@@ -131,17 +138,16 @@ def main() -> None:
     if args.verify:
         # verify against the CURRENT (post-delta) graph version; only the
         # final wave's answers are guaranteed to reflect it.
-        from repro.core.sssp.reference import dijkstra
-        final = final_wave
-        hg_now = service.solver.graph.to_host()
-        bad = 0
-        for q in final[:16]:
-            exp = dijkstra(hg_now, source=q.source).dist[q.target]
-            got = q.distance if q.distance is not None else float("inf")
-            exp = exp if np.isfinite(exp) else float("inf")
-            if not np.isclose(got, exp, rtol=1e-5, atol=1e-4):
-                bad += 1
-        print(f"  verified {min(len(final), 16)} answers against dijkstra: "
+        from repro.core.sssp.reference import scipy_dijkstra
+        final = final_wave[:16]
+        srcs = list(dict.fromkeys(q.source for q in final))
+        ref = scipy_dijkstra(service.solver.graph.to_host(), srcs)
+        row = {s: i for i, s in enumerate(srcs)}
+        got = np.array([np.inf if q.distance is None else q.distance
+                        for q in final])
+        exp = np.array([ref[row[q.source], q.target] for q in final])
+        bad = int(np.sum(~np.isclose(got, exp, rtol=1e-5, atol=1e-4)))
+        print(f"  verified {len(final)} answers against scipy dijkstra: "
               f"{'OK' if bad == 0 else f'{bad} MISMATCHES'}")
         if bad:
             sys.exit(1)

@@ -104,6 +104,18 @@ def test_walk_jaxpr_marks_hot_region():
     assert cond and "sort" not in cond
 
 
+def test_walk_jaxpr_recurses_into_shard_map():
+    """The distributed route is a ``jax.shard_map`` equation; the walk
+    must reach the round loop and its ``pmin`` all-reduce inside it."""
+    from repro.analysis.routes import build_routes
+    route = build_routes(include=("distributed.batched",))[
+        "distributed.batched"]
+    sites = walk_jaxpr(route.jaxpr)
+    assert "shard_map" in {s.prim for s in sites}
+    hot = {s.prim for s in sites if s.hot}
+    assert {"pmin", "gather"} <= hot
+
+
 def test_forbid_hot_sort_and_dense_budget():
     spec = ContractSpec(name="toy", routes=("toy.*",),
                         forbid_hot=("sort",), dense_budget=0)
@@ -112,6 +124,42 @@ def test_forbid_hot_sort_and_dense_budget():
     assert v.verdict == "FAIL"
     rules = {x.rule for x in v.violations}
     assert "forbid_hot:sort" in rules
+
+
+@pytest.mark.parametrize("dtype,fails", [(jnp.bool_, True),
+                                         (jnp.int32, False)])
+def test_hot_bool_gather_fails(dtype, fails):
+    """A mask gathered as bool inside the round loop fails the route
+    (it miscompiled under vmap on TPU v5e); the int32 form passes."""
+    def f(x, m, idx):
+        def body(c):
+            return jnp.where(m.astype(dtype)[idx] > 0, c * 0.5, c)
+
+        return jax.lax.while_loop(lambda c: c[0] < 10.0, body, x)
+
+    jx = jax.make_jaxpr(jax.vmap(f, in_axes=(0, 0, None)))(
+        jnp.zeros((2, 128), jnp.float32), jnp.zeros((2, 128), bool),
+        jnp.arange(128))
+    spec = ContractSpec(name="toy", routes=("toy.*",))
+    v = lint_route("toy.batched", jx, specs={"toy": spec}, waivers=())
+    rules = {x.rule for x in v.violations}
+    assert ("gather:bool" in rules) is fails
+    assert (v.verdict == "FAIL") is fails
+
+
+def test_bool_gather_outside_loop_fails():
+    """The rule covers the whole program, not only the round loop: a
+    bool gather in set-up code (init, delta application) fails too."""
+    def f(x, m, idx):
+        return jnp.where(m[idx], x * 0.5, x)
+
+    jx = jax.make_jaxpr(jax.vmap(f, in_axes=(0, 0, None)))(
+        jnp.zeros((2, 128), jnp.float32), jnp.zeros((2, 128), bool),
+        jnp.arange(128))
+    spec = ContractSpec(name="toy", routes=("toy.*",))
+    v = lint_route("toy.batched", jx, specs={"toy": spec}, waivers=())
+    assert "gather:bool" in {x.rule for x in v.violations}
+    assert v.verdict == "FAIL"
 
 
 def test_dense_pass_count_keys_on_dims():

@@ -1,7 +1,10 @@
 """Registry completeness, cell builders, HLO collective parser."""
 
+import pytest
+
 from repro.configs import get_arch, list_archs
-from repro.launch.roofline import (RooflineTerms, parse_collective_bytes)
+from repro.launch.roofline import (RooflineTerms, parse_collective_bytes,
+                                   peaks)
 
 ASSIGNED = [
     "deepseek-moe-16b", "llama4-maverick-400b-a17b", "command-r-35b",
@@ -94,6 +97,13 @@ def test_roofline_terms_math():
     assert abs(t.t_memory - 1.0) < 1e-9
     assert abs(t.t_collective - 1.0) < 1e-9
     assert abs(t.roofline_fraction - 0.5) < 1e-9
+
+
+@pytest.mark.parametrize("kind", ["cpu", "TPU v4", ""])
+def test_peaks_refuse_unknown_device_kind(kind):
+    assert peaks("TPU v5 lite")["hbm_bw"] == 819e9
+    with pytest.raises(ValueError, match="no published peaks"):
+        peaks(kind)
 
 
 def test_lm_smoke_cells_buildable():

@@ -36,6 +36,7 @@ print("SUBPROCESS-OK")
 
 def run_with_devices(script: str, n_dev: int = 8) -> str:
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"     # never reach for a chip from a child
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n_dev}"
     env["PYTHONPATH"] = os.path.join(
         os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "src")

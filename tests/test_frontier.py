@@ -326,7 +326,8 @@ def test_frontier_scatter_min_kernel_matches_ref():
         tgt = rng.integers(0, n + 1, (cap, deg)).astype(np.int32)
         cand = rng.uniform(0.0, 9.0, (cap, deg)).astype(np.float32)
         cand = np.where(tgt == n, np.inf, cand).astype(np.float32)
-        got = frontier_scatter_min(jnp.asarray(tgt), jnp.asarray(cand), n)
+        got = frontier_scatter_min(jnp.asarray(tgt), jnp.asarray(cand), n,
+                                   interpret=True)
         want = ref.frontier_scatter_min_ref(jnp.asarray(tgt),
                                             jnp.asarray(cand), n)
         assert _bitwise(got, want), (n, cap, deg)
@@ -342,7 +343,8 @@ def test_frontier_scatter_min_batch_kernel_matches_ref():
         cand = rng.uniform(0.0, 9.0, (B, cap, deg)).astype(np.float32)
         cand = np.where(tgt[None] == n, np.inf, cand).astype(np.float32)
         got = frontier_scatter_min_batch(jnp.asarray(tgt),
-                                         jnp.asarray(cand), n)
+                                         jnp.asarray(cand), n,
+                                         interpret=True)
         want = ref.frontier_scatter_min_batch_ref(jnp.asarray(tgt),
                                                   jnp.asarray(cand), n)
         assert _bitwise(got, want), (n, cap, deg, B)

@@ -21,7 +21,8 @@ def test_relax_ell_sweep(n, deg, dtype):
     d_src[rng.random((n, deg)) < 0.1] = np.inf   # undiscovered sources
     w = rng.uniform(0.1, 1, (n, deg)).astype(dtype)
     mask = rng.random((n, deg)) < 0.7
-    got = relax_ell(jnp.asarray(d_src), jnp.asarray(w), jnp.asarray(mask))
+    got = relax_ell(jnp.asarray(d_src), jnp.asarray(w), jnp.asarray(mask),
+                    interpret=True)
     exp = ref.relax_ell_ref(jnp.asarray(d_src), jnp.asarray(w),
                             jnp.asarray(mask))
     assert np.array_equal(np.asarray(got), np.asarray(exp))  # min: exact
@@ -31,7 +32,7 @@ def test_relax_ell_sweep(n, deg, dtype):
 def test_masked_min_sweep(n):
     x = rng.uniform(-100, 100, n).astype(np.float32)
     m = rng.random(n) < 0.4
-    got = masked_min(jnp.asarray(x), jnp.asarray(m))
+    got = masked_min(jnp.asarray(x), jnp.asarray(m), interpret=True)
     exp = ref.masked_min_ref(jnp.asarray(x), jnp.asarray(m))
     assert np.array_equal(np.asarray(got), np.asarray(exp))
 
@@ -39,7 +40,7 @@ def test_masked_min_sweep(n):
 def test_masked_min_empty_mask_is_inf():
     x = rng.uniform(0, 1, 100).astype(np.float32)
     assert np.isinf(np.asarray(
-        masked_min(jnp.asarray(x), jnp.zeros(100, bool))))
+        masked_min(jnp.asarray(x), jnp.zeros(100, bool), interpret=True)))
 
 
 @pytest.mark.parametrize("B,H,M,D,K", [
