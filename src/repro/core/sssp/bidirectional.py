@@ -56,7 +56,7 @@ import numpy as np
 
 from repro.analysis.contracts import contract
 from repro.core.graph import Graph, HostGraph, INF
-from repro.core.sssp import backends
+from repro.core.sssp import backends, spans
 from repro.core.sssp.engine import (SP4_CONFIG, SSSPConfig, SSSPResult,
                                     _fixed_by_dict, _init_state, _round,
                                     _solve_warm, delta_taint_seeds)
@@ -137,10 +137,11 @@ class BidiResult:
             return None
         from repro.core.sssp.parents import extract_path, parent_pointers
         m = int(self.meeting)
-        fwd = extract_path(np.asarray(parent_pointers(self.graph, self.D[0])),
-                           m, self.source)
-        bwd = extract_path(np.asarray(parent_pointers(self.rgraph, self.D[1])),
-                           m, self.target)
+        fwd_par, bwd_par = spans.fetch(
+            (parent_pointers(self.graph, self.D[0]),
+             parent_pointers(self.rgraph, self.D[1])))
+        fwd = extract_path(fwd_par, m, self.source)
+        bwd = extract_path(bwd_par, m, self.target)
         if fwd is None or bwd is None:
             return None
         self._path = fwd + bwd[::-1][1:]
@@ -369,14 +370,15 @@ class BidirectionalSolver:
                 g2_old, self._g2, delta2,
                 jnp.asarray(D0, jnp.float32), jnp.asarray(F0, bool))
             self.warm_solves += 1
+            mu, meet, fb, rounds = spans.fetch(
+                (mu, meet, final.fixed_by, final.round))
             dist = float(mu)
-            fb = np.asarray(final.fixed_by).sum(axis=0)
             res = BidiResult(
                 source=int(source), target=int(target), distance=dist,
                 meeting=int(meet) if np.isfinite(dist) else None,
-                rounds=int(final.round[0]),
+                rounds=int(rounds[0]),
                 D=final.D, C=final.C, fixed=final.fixed,
-                fixed_by=_fixed_by_dict(fb),
+                fixed_by=_fixed_by_dict(fb.sum(axis=0)),
                 graph=self.graph, rgraph=self.rgraph, mu=dist)
             if np.isfinite(dist):
                 p = res.path()
@@ -437,17 +439,17 @@ class BidirectionalSolver:
         ends = jnp.asarray([int(source), int(target)], jnp.int32)
         final, mu, meet = self._jit(self._g2, self._csr2, ends, C0)
         self.solves += 1
+        mu, meet, fb, rounds, edges = spans.fetch(
+            (mu, meet, final.fixed_by, final.round, final.edges))
         dist = float(mu)
-        fb = np.asarray(final.fixed_by).sum(axis=0)
         res = BidiResult(
             source=int(source), target=int(target), distance=dist,
             meeting=int(meet) if np.isfinite(dist) else None,
-            rounds=int(final.round[0]),
+            rounds=int(rounds[0]),
             D=final.D, C=final.C, fixed=final.fixed,
-            fixed_by=_fixed_by_dict(fb),
+            fixed_by=_fixed_by_dict(fb.sum(axis=0)),
             graph=self.graph, rgraph=self.rgraph, mu=dist,
-            edges_relaxed=None if final.edges is None
-            else int(np.asarray(final.edges).sum()))
+            edges_relaxed=None if edges is None else int(edges.sum()))
         if np.isfinite(dist):
             p = res.path()
             if p is not None:

@@ -53,8 +53,9 @@ import numpy as np
 
 from repro.analysis.contracts import contract
 from repro.core.graph import Graph
+from repro.core.sssp import spans
 from repro.core.sssp.engine import (SP4_CONFIG, SSSPConfig, SSSPResult,
-                                    _fixed_by_dict, _solve_warm,
+                                    _fixed_by_dict, _scope, _solve_warm,
                                     _solve_warm_frontier,
                                     delta_decrease_sources,
                                     delta_taint_seeds)
@@ -305,9 +306,12 @@ class DynamicSolver(Solver):
         lane).  Warm results stay bitwise-identical to the dense body.
         """
         self._count_warm_trace()
-        g_new = g_old.apply_delta(delta)
-        ell_new = None if ell_old is None else ell_old.apply_delta(delta)
-        csr_new = None if csr_old is None else csr_old.apply_delta(delta)
+        with _scope("sssp.taint"):
+            g_new = g_old.apply_delta(delta)
+            ell_new = (None if ell_old is None
+                       else ell_old.apply_delta(delta))
+            csr_new = (None if csr_old is None
+                       else csr_old.apply_delta(delta))
         prims = self._make_prims(g_new, ell_new, csr_new)
         if getattr(prims, "relax_frontier_b", None) is not None:
             seeds, pure = jax.vmap(
@@ -316,17 +320,16 @@ class DynamicSolver(Solver):
             states, sweeps, taint = _solve_warm_frontier(
                 g_new, self.cfg, prev_D, prev_fixed, seeds, pure, prims,
                 dec_src=dec)
-            return (g_new, ell_new, csr_new, states, sweeps,
-                    jnp.sum(taint, axis=1))
+        else:
+            def one(D0, f0):
+                seeds, pure = delta_taint_seeds(g_old, delta, D0)
+                return _solve_warm(g_new, self.cfg, D0, f0, seeds, pure,
+                                   prims=prims)
 
-        def one(D0, f0):
-            seeds, pure = delta_taint_seeds(g_old, delta, D0)
-            return _solve_warm(g_new, self.cfg, D0, f0, seeds, pure,
-                               prims=prims)
-
-        states, sweeps, taint = jax.vmap(one)(prev_D, prev_fixed)
-        return g_new, ell_new, csr_new, states, sweeps, jnp.sum(taint,
-                                                                axis=1)
+            states, sweeps, taint = jax.vmap(one)(prev_D, prev_fixed)
+        with _scope("sssp.count"):
+            tainted = jnp.sum(taint, axis=1)
+        return g_new, ell_new, csr_new, states, sweeps, tainted
 
     # ------------------------------------------------------------------
     def _track(self, source: int, *, D, C, fixed, rounds, fixed_by) -> None:
@@ -373,13 +376,16 @@ class DynamicSolver(Solver):
         through the compiled warm program; requested sources without one
         are cold-solved on the mutated graph.  Untouched tracked states
         become stale (version mismatch) and are refreshed lazily by
-        ``resolve``.  Returns a stats dict (see keys below).
+        ``resolve``.  Returns a stats dict (see keys below):
+        ``warm_rounds`` and ``tainted`` per warm lane, ``sweeps`` the
+        most over them, ``cold_rounds`` the cold refresh's rounds (the
+        most over its lanes; 0 without one).
         """
         if not isinstance(delta, GraphDelta):
             raise TypeError(f"update() wants a GraphDelta (see make_delta); "
                             f"got {type(delta)!r}")
-        didx = np.asarray(delta.edge_idx)[: delta.k]
-        dw = np.asarray(delta.new_w)[: delta.k]
+        didx, dw = spans.fetch((delta.edge_idx, delta.new_w))
+        didx, dw = didx[: delta.k], dw[: delta.k]
         old_src = self.graph.src   # carry the CSR perm across versions
         # async device gather of the k OLD weights (for the stats
         # counters); the blocking np.asarray happens only after the warm
@@ -396,7 +402,7 @@ class DynamicSolver(Solver):
         stats = dict(edges_changed=delta.k, increased=0, decreased=0,
                      warm_refreshed=len(warm_src),
                      cold_refreshed=len(cold_src), sweeps=0,
-                     warm_rounds=[], tainted=[])
+                     warm_rounds=[], tainted=[], cold_rounds=0)
         if warm_src:
             b = len(warm_src)
             b_pad = _next_pow2(b)
@@ -408,15 +414,15 @@ class DynamicSolver(Solver):
                 self.graph, self.ell, self.csr, delta, prev_D, prev_F)
             self.graph, self.ell, self.csr = g_new, ell_new, csr_new
             self.version += 1
-            fb = np.asarray(states.fixed_by)
-            rounds = np.asarray(states.round)
+            fb, rounds, sweeps, tainted = spans.fetch(
+                (states.fixed_by, states.round, sweeps, tainted))
             for i, s in enumerate(warm_src):
                 self._track(s, D=states.D[i], C=states.C[i],
                             fixed=states.fixed[i], rounds=rounds[i],
                             fixed_by=_fixed_by_dict(fb[i]))
-            stats["sweeps"] = int(np.max(np.asarray(sweeps)[:b]))
+            stats["sweeps"] = int(np.max(sweeps[:b]))
             stats["warm_rounds"] = [int(r) for r in rounds[:b]]
-            stats["tainted"] = [int(t) for t in np.asarray(tainted)[:b]]
+            stats["tainted"] = [int(t) for t in tainted[:b]]
         else:
             # no warm candidates: mutate the layouts eagerly (still no
             # retrace — apply_delta is shape-stable), bump the version.
@@ -428,8 +434,9 @@ class DynamicSolver(Solver):
             self.version += 1
         _carry_csr_perm(old_src, self.graph.src)
         if cold_src:
-            self.solve_batch(cold_src)
-        old_w = np.asarray(old_w_dev)   # blocks AFTER the update dispatched
+            stats["cold_rounds"] = int(np.max(
+                self.solve_batch(cold_src).rounds))
+        old_w = spans.fetch(old_w_dev)  # blocks AFTER the update dispatched
         stats["increased"] = int(np.sum(dw > old_w))
         stats["decreased"] = int(np.sum(dw < old_w))
         return stats

@@ -35,6 +35,22 @@ and the edge-sharded ``shard_map`` path are instances of one program.
 The public surface is the :class:`~repro.core.sssp.solver.Solver` facade
 (``repro.sssp``); the ``run_sssp*`` functions below remain as thin
 compatibility shims.
+
+Every device operation of a round carries the pass it belongs to as
+the outermost ``sssp.*`` named scope in its ``op_name`` metadata, so a
+profiler trace can sum device time by pass (docs/round-anatomy.md):
+
+  sssp.relax     step-1 D relaxation (dense fallback and ``relax2``)
+  sssp.lb        step 3: Lemma-7 lift, Eqn (1), the c_fix/cone walks
+  sssp.inw       inWeight_nf, dense or the incremental refresh
+  sssp.fix       step-2 reductions and every fixing/un-fixing rule
+  sssp.frontier  next-round fresh mask and its compaction
+  sssp.freeze    loop predicates, lane liveness and select-freeze
+  sssp.count     edges, fixed_by and round accounting
+  sssp.taint     delta set-up: layouts, taint seeds, cone sweeps
+  sssp.init      initial state and carries inside the program
+
+Scopes are metadata only: they change no jaxpr equation.
 """
 from __future__ import annotations
 
@@ -47,9 +63,10 @@ import numpy as np
 
 from repro.analysis.contracts import contract
 from repro.core.graph import Graph, INF
-from repro.core.sssp import backends
+from repro.core.sssp import backends, spans
 
 Rules = frozenset
+_scope = jax.named_scope
 
 
 @dataclasses.dataclass(frozen=True)
@@ -156,7 +173,8 @@ class SSSPResult:
                 raise ValueError("result carries no graph; "
                                  "solve via Solver/run_sssp to attach one")
             from repro.core.sssp.parents import parent_pointers
-            self._parents = np.asarray(parent_pointers(self.graph, self.dist))
+            self._parents = spans.fetch(parent_pointers(self.graph,
+                                                        self.dist))
         return self._parents
 
     def path_to(self, target: int) -> list[int] | None:
@@ -213,22 +231,24 @@ def _init_state(g: Graph, source, C0=None,
     new at round 1 — the label-setting round 1 relaxes nothing and masks
     it out, bitwise-identical either way).
     """
-    D = jnp.full((g.n,), INF, jnp.float32).at[source].set(0.0)
-    if C0 is None:
-        C = jnp.zeros((g.n,), jnp.float32)
-    else:
-        C = jnp.maximum(C0.astype(jnp.float32), 0.0)
-    fixed = jnp.zeros((g.n,), bool)
-    cap = _frontier_cap(prims)
-    f_idx = f_cnt = edges = None
-    if cap:
-        f_idx = jnp.full((cap,), g.n, jnp.int32).at[0].set(
-            jnp.int32(source))
-        f_cnt = jnp.int32(1)
-        edges = jnp.int32(0)
-    return SSSPState(D=D, C=C, fixed=fixed, explored=fixed,
-                     round=jnp.int32(0), fixed_by=jnp.zeros(5, jnp.int32),
-                     f_idx=f_idx, f_cnt=f_cnt, edges=edges)
+    with _scope("sssp.init"):
+        D = jnp.full((g.n,), INF, jnp.float32).at[source].set(0.0)
+        if C0 is None:
+            C = jnp.zeros((g.n,), jnp.float32)
+        else:
+            C = jnp.maximum(C0.astype(jnp.float32), 0.0)
+        fixed = jnp.zeros((g.n,), bool)
+        cap = _frontier_cap(prims)
+        f_idx = f_cnt = edges = None
+        if cap:
+            f_idx = jnp.full((cap,), g.n, jnp.int32).at[0].set(
+                jnp.int32(source))
+            f_cnt = jnp.int32(1)
+            edges = jnp.int32(0)
+        return SSSPState(D=D, C=C, fixed=fixed, explored=fixed,
+                         round=jnp.int32(0),
+                         fixed_by=jnp.zeros(5, jnp.int32),
+                         f_idx=f_idx, f_cnt=f_cnt, edges=edges)
 
 
 def delta_taint_seeds(g_old: Graph, delta, D0: jax.Array):
@@ -251,19 +271,21 @@ def delta_taint_seeds(g_old: Graph, delta, D0: jax.Array):
     clipped gathers + the masked conditions, never by data-dependent
     shapes.
     """
-    valid = delta.edge_idx < g_old.e_pad
-    idx = jnp.minimum(delta.edge_idx, g_old.e_pad - 1)  # clip for gathers
-    w_old = g_old.w[idx]
-    src, dst = g_old.src[idx], g_old.dst[idx]
-    D0_ext = jnp.concatenate([D0, jnp.full((1,), INF, D0.dtype)])
-    Ds = D0_ext[jnp.minimum(src, g_old.n)]
-    Dd = D0_ext[jnp.minimum(dst, g_old.n)]
-    increased = valid & (delta.new_w > w_old)
-    tight = (Ds + w_old <= Dd) & (Ds < INF) & (Dd < INF)
-    seed_at = jnp.where(increased & tight, dst, g_old.n)  # n = drop
-    seeds = jnp.zeros((g_old.n,), bool).at[seed_at].set(True, mode="drop")
-    pure_increase = ~jnp.any(valid & (delta.new_w < w_old))
-    return seeds, pure_increase
+    with _scope("sssp.taint"):
+        valid = delta.edge_idx < g_old.e_pad
+        idx = jnp.minimum(delta.edge_idx, g_old.e_pad - 1)  # clip gathers
+        w_old = g_old.w[idx]
+        src, dst = g_old.src[idx], g_old.dst[idx]
+        D0_ext = jnp.concatenate([D0, jnp.full((1,), INF, D0.dtype)])
+        Ds = D0_ext[jnp.minimum(src, g_old.n)]
+        Dd = D0_ext[jnp.minimum(dst, g_old.n)]
+        increased = valid & (delta.new_w > w_old)
+        tight = (Ds + w_old <= Dd) & (Ds < INF) & (Dd < INF)
+        seed_at = jnp.where(increased & tight, dst, g_old.n)  # n = drop
+        seeds = jnp.zeros((g_old.n,), bool).at[seed_at].set(
+            True, mode="drop")
+        pure_increase = ~jnp.any(valid & (delta.new_w < w_old))
+        return seeds, pure_increase
 
 
 def delta_decrease_sources(g_old: Graph, delta) -> jax.Array:
@@ -276,11 +298,12 @@ def delta_decrease_sources(g_old: Graph, delta) -> jax.Array:
     (``_init_state_warm``).  Source-independent — one mask serves every
     vmapped lane of a warm refresh batch.
     """
-    valid = delta.edge_idx < g_old.e_pad
-    idx = jnp.minimum(delta.edge_idx, g_old.e_pad - 1)
-    dec = valid & (delta.new_w < g_old.w[idx])
-    at = jnp.where(dec, g_old.src[idx], g_old.n)  # n = drop
-    return jnp.zeros((g_old.n,), bool).at[at].set(True, mode="drop")
+    with _scope("sssp.taint"):
+        valid = delta.edge_idx < g_old.e_pad
+        idx = jnp.minimum(delta.edge_idx, g_old.e_pad - 1)
+        dec = valid & (delta.new_w < g_old.w[idx])
+        at = jnp.where(dec, g_old.src[idx], g_old.n)  # n = drop
+        return jnp.zeros((g_old.n,), bool).at[at].set(True, mode="drop")
 
 
 def _warm_seed_mask(g: Graph, taint: jax.Array, fixed: jax.Array,
@@ -289,13 +312,14 @@ def _warm_seed_mask(g: Graph, taint: jax.Array, fixed: jax.Array,
     folded into the warm state: the taint cone's in-boundary plus tails
     of decreased delta edges (see ``_init_state_warm``).  ``dec_src=None``
     degrades to seeding every surviving fixed vertex — still exact."""
-    if dec_src is None:
-        return fixed & (D < INF)
-    # in-boundary of the cone: fixed tails of edges into taint
-    at = jnp.where(g.gather_dst(taint.astype(jnp.int32), fill=0) > 0,
-                   g.src, g.n)
-    bnd = jnp.zeros((g.n,), bool).at[at].set(True, mode="drop")
-    return (bnd | dec_src) & fixed & (D < INF)
+    with _scope("sssp.taint"):
+        if dec_src is None:
+            return fixed & (D < INF)
+        # in-boundary of the cone: fixed tails of edges into taint
+        at = jnp.where(g.gather_dst(taint.astype(jnp.int32), fill=0) > 0,
+                       g.src, g.n)
+        bnd = jnp.zeros((g.n,), bool).at[at].set(True, mode="drop")
+        return (bnd | dec_src) & fixed & (D < INF)
 
 
 def _init_state_warm(g: Graph, prev_D: jax.Array, prev_fixed: jax.Array,
@@ -358,25 +382,28 @@ def _init_state_warm(g: Graph, prev_D: jax.Array, prev_fixed: jax.Array,
         taint2 = taint | ((reach <= prev_D) & (prev_D < INF))
         return taint2, jnp.any(taint2 != taint), i + jnp.int32(1)
 
-    taint, _, sweeps = jax.lax.while_loop(
-        cond, body, (seeds, jnp.any(seeds), jnp.int32(0)))
+    with _scope("sssp.taint"):
+        taint, _, sweeps = jax.lax.while_loop(
+            cond, body, (seeds, jnp.any(seeds), jnp.int32(0)))
 
-    fixed = prev_fixed & ~taint
-    D = jnp.where(taint, INF, prev_D)
-    C = jnp.where(
-        fixed, D,
-        jnp.where(pure_increase & prev_fixed & (prev_D < INF), prev_D, 0.0))
-    cap = _frontier_cap(prims)
-    f_idx = f_cnt = edges = None
-    if cap:
-        seed_mask = _warm_seed_mask(g, taint, fixed, D, dec_src)
-        f_idx, f_cnt = _compact_frontier(seed_mask, cap, g.n)
-        edges = jnp.int32(0)
-    state = SSSPState(D=D, C=C, fixed=fixed,
-                      explored=jnp.zeros_like(fixed), round=jnp.int32(0),
-                      fixed_by=jnp.zeros(5, jnp.int32),
-                      f_idx=f_idx, f_cnt=f_cnt, edges=edges)
-    return state, sweeps, taint
+        fixed = prev_fixed & ~taint
+        D = jnp.where(taint, INF, prev_D)
+        C = jnp.where(
+            fixed, D,
+            jnp.where(pure_increase & prev_fixed & (prev_D < INF), prev_D,
+                      0.0))
+        cap = _frontier_cap(prims)
+        f_idx = f_cnt = edges = None
+        if cap:
+            seed_mask = _warm_seed_mask(g, taint, fixed, D, dec_src)
+            f_idx, f_cnt = _compact_frontier(seed_mask, cap, g.n)
+            edges = jnp.int32(0)
+        state = SSSPState(D=D, C=C, fixed=fixed,
+                          explored=jnp.zeros_like(fixed),
+                          round=jnp.int32(0),
+                          fixed_by=jnp.zeros(5, jnp.int32),
+                          f_idx=f_idx, f_cnt=f_cnt, edges=edges)
+        return state, sweeps, taint
 
 
 def _solve_warm(g: Graph, cfg: SSSPConfig, prev_D, prev_fixed, seeds,
@@ -473,119 +500,133 @@ def _round(g: Graph, cfg: SSSPConfig, state: SSSPState,
     # SP4).  Relax FIRST, from previously-fixed sources (whose D is final),
     # so every fixing rule below sees a D in which all out-edges of all
     # fixed vertices have been applied — the invariant Lemma 2/5/8 need.
-    if cfg.label_correcting:
-        relax_src = D < INF      # Bellman-Ford style: every discovered edge
-    else:
-        relax_src = fixed        # label-setting: out-edges of fixed vertices
+    with _scope("sssp.relax"):
+        if cfg.label_correcting:
+            relax_src = D < INF  # Bellman-Ford style: every discovered edge
+        else:
+            relax_src = fixed    # label-setting: out-edges of fixed vertices
 
     need_inw = ("in" in cfg.rules) or ("pred" in cfg.rules)
     in_w_nf = None
     edges = state.edges
     if use_frontier:
         cap = prims.frontier_cap
-        if cap >= g.n:
-            # a buffer the size of the vertex set can never overflow, so
-            # the fallback branch vanishes STATICALLY — this matters for
-            # vmapped (batched) solves, where a data-dependent lax.cond
-            # linearizes to select and would execute BOTH branches every
-            # round (dense + sparse); frontier_cap >= n is the escape
-            # hatch that keeps batches single-branch.
-            overflow = jnp.bool_(False)
-            D_relax = prims.relax_frontier(D, state.f_idx, relax_src)
-        else:
-            overflow = state.f_cnt > cap
-            D_relax = jax.lax.cond(
-                overflow,
-                lambda: prims.relax(D, relax_src),
-                lambda: prims.relax_frontier(D, state.f_idx, relax_src))
+        with _scope("sssp.relax"):
+            if cap >= g.n:
+                # a buffer the size of the vertex set can never overflow,
+                # so the fallback branch vanishes STATICALLY — this
+                # matters for vmapped (batched) solves, where a
+                # data-dependent lax.cond linearizes to select and would
+                # execute BOTH branches every round (dense + sparse);
+                # frontier_cap >= n is the escape hatch that keeps
+                # batches single-branch.
+                overflow = jnp.bool_(False)
+                D_relax = prims.relax_frontier(D, state.f_idx, relax_src)
+            else:
+                overflow = state.f_cnt > cap
+                D_relax = jax.lax.cond(
+                    overflow,
+                    lambda: prims.relax(D, relax_src),
+                    lambda: prims.relax_frontier(D, state.f_idx, relax_src))
         if need_inw:
-            in_w_nf = prims.in_weight_nf(~fixed)
+            with _scope("sssp.inw"):
+                in_w_nf = prims.in_weight_nf(~fixed)
         # edges-relaxed accounting: actual out-degrees of the masked
         # buffer on sparse rounds, the whole padded edge list on dense
         # fallback rounds.
-        u = jnp.minimum(state.f_idx, g.n - 1)
-        deg = jnp.where(relax_src, g.out_deg, 0)[u]
-        sparse_edges = jnp.sum(jnp.where(state.f_idx < g.n, deg, 0),
-                               dtype=jnp.int32)
-        edges = edges + jnp.where(overflow, jnp.int32(g.e_pad),
-                                  sparse_edges)
+        with _scope("sssp.count"):
+            u = jnp.minimum(state.f_idx, g.n - 1)
+            deg = jnp.where(relax_src, g.out_deg, 0)[u]
+            sparse_edges = jnp.sum(jnp.where(state.f_idx < g.n, deg, 0),
+                                   dtype=jnp.int32)
+            edges = edges + jnp.where(overflow, jnp.int32(g.e_pad),
+                                      sparse_edges)
     elif need_inw and prims.relax2 is not None:
-        D_relax, in_w_nf = prims.relax2(D, relax_src, ~fixed)
+        with _scope("sssp.relax"):
+            D_relax, in_w_nf = prims.relax2(D, relax_src, ~fixed)
     else:
-        D_relax = prims.relax(D, relax_src)
+        with _scope("sssp.relax"):
+            D_relax = prims.relax(D, relax_src)
         if need_inw:
-            in_w_nf = prims.in_weight_nf(~fixed)
+            with _scope("sssp.inw"):
+                in_w_nf = prims.in_weight_nf(~fixed)
     if warm:
         # weight decreases can leave a warm-started fixed vertex stale-high;
         # un-fix it the moment relaxation offers something strictly better
         # (its old D stays a valid upper bound meanwhile, so the relax it
         # sourced this round was still sound).
-        improved = fixed & (D_relax < D)
-        fixed = fixed & ~improved
-        # its C had been lifted to the now-stale D; drop it back to a
-        # trivially-valid lower bound before the lb rule sees it again.
-        C = jnp.where(improved, 0.0, C)
-    D = jnp.where(~fixed, jnp.minimum(D, D_relax), D)
+        with _scope("sssp.fix"):
+            improved = fixed & (D_relax < D)
+            fixed = fixed & ~improved
+            # its C had been lifted to the now-stale D; drop it back to a
+            # trivially-valid lower bound before the lb rule sees it again.
+            C = jnp.where(improved, 0.0, C)
+    with _scope("sssp.relax"):
+        D = jnp.where(~fixed, jnp.minimum(D, D_relax), D)
     explored = fixed  # all currently-fixed vertices are now relaxed-at-final-D
 
-    discovered = D < INF
-    active = discovered & ~fixed
-
     # --- Step 2: global reductions (the heap minima of SP1–SP3) ---
-    minD = prims.masked_min(D, active)
-    new_fix = jnp.zeros_like(fixed)
-    rule_counts = []
+    with _scope("sssp.fix"):
+        discovered = D < INF
+        active = discovered & ~fixed
+        minD = prims.masked_min(D, active)
+        new_fix = jnp.zeros_like(fixed)
+        rule_counts = []
 
-    def count(mask):
-        rule_counts.append(jnp.sum(mask & active & ~new_fix, dtype=jnp.int32))
-        return mask
+        def count(mask):
+            rule_counts.append(jnp.sum(mask & active & ~new_fix,
+                                       dtype=jnp.int32))
+            return mask
 
-    # R_min (Dijkstra's own rule; guarantees >=1 vertex fixed per round)
-    if "min" in cfg.rules:
-        new_fix = new_fix | count(active & (D <= minD))
-    else:
-        rule_counts.append(jnp.int32(0))
+        # R_min (Dijkstra's own rule; guarantees >=1 vertex fixed per round)
+        if "min" in cfg.rules:
+            new_fix = new_fix | count(active & (D <= minD))
+        else:
+            rule_counts.append(jnp.int32(0))
 
-    # R_pred (SP1, Lemma 2): no in-edge from a non-fixed source remains;
-    # all in-edges relaxed (step 1) => D final.  Derived from inWeight_nf
-    # (min over an empty set is +inf) — no separate reduction.
-    if "pred" in cfg.rules:
-        has_nf_pred = ~jnp.isinf(in_w_nf)
-        new_fix = new_fix | count(active & ~has_nf_pred)
-    else:
-        rule_counts.append(jnp.int32(0))
+        # R_pred (SP1, Lemma 2): no in-edge from a non-fixed source
+        # remains; all in-edges relaxed (step 1) => D final.  Derived from
+        # inWeight_nf (min over an empty set is +inf) — no separate
+        # reduction.
+        if "pred" in cfg.rules:
+            has_nf_pred = ~jnp.isinf(in_w_nf)
+            new_fix = new_fix | count(active & ~has_nf_pred)
+        else:
+            rule_counts.append(jnp.int32(0))
 
-    # R_in (SP2, Lemma 5 strengthened): D[x] <= minD + min in-weight over
-    # edges that can still relax (source not yet fixed).  Any pending
-    # contribution is cost[v]+w >= minD + inWeight_nf[x] >= D[x].
-    if "in" in cfg.rules:
-        new_fix = new_fix | count(active & (D <= minD + in_w_nf))
-    else:
-        rule_counts.append(jnp.int32(0))
+        # R_in (SP2, Lemma 5 strengthened): D[x] <= minD + min in-weight
+        # over edges that can still relax (source not yet fixed).  Any
+        # pending contribution is cost[v]+w >= minD + inWeight_nf[x] >= D[x].
+        if "in" in cfg.rules:
+            new_fix = new_fix | count(active & (D <= minD + in_w_nf))
+        else:
+            rule_counts.append(jnp.int32(0))
 
-    # R_out (Lemma 8 / Crauser out-version)
-    if "out" in cfg.rules:
-        threshold = prims.masked_min(D + g.out_weight, active)
-        new_fix = new_fix | count(active & (D <= threshold))
-    else:
-        rule_counts.append(jnp.int32(0))
+        # R_out (Lemma 8 / Crauser out-version)
+        if "out" in cfg.rules:
+            threshold = prims.masked_min(D + g.out_weight, active)
+            new_fix = new_fix | count(active & (D <= threshold))
+        else:
+            rule_counts.append(jnp.int32(0))
 
-    fixed1 = fixed | new_fix
+        fixed1 = fixed | new_fix
 
     # --- Step 3: C update (Lemma 7 lift, then Lemma 6 / Eqn (1)) ---
     if "lb" in cfg.rules:
-        C = jnp.where(fixed1, D, jnp.maximum(C, minD))
-        all_src = jnp.ones_like(fixed)
-        for _ in range(cfg.c_prop_iters):
-            c_in = prims.relax(C, all_src)
-            C = jnp.where(~fixed1, jnp.maximum(C, c_in), C)
-        fix_lb = ~fixed1 & discovered & (C >= D)
-        rule_counts.append(jnp.sum(fix_lb, dtype=jnp.int32))
-        fixed2 = fixed1 | fix_lb
-        C = jnp.where(fixed2, D, C)
+        with _scope("sssp.lb"):
+            C = jnp.where(fixed1, D, jnp.maximum(C, minD))
+            all_src = jnp.ones_like(fixed)
+            for _ in range(cfg.c_prop_iters):
+                c_in = prims.relax(C, all_src)
+                C = jnp.where(~fixed1, jnp.maximum(C, c_in), C)
+        with _scope("sssp.fix"):
+            fix_lb = ~fixed1 & discovered & (C >= D)
+            rule_counts.append(jnp.sum(fix_lb, dtype=jnp.int32))
+            fixed2 = fixed1 | fix_lb
     else:
         rule_counts.append(jnp.int32(0))
         fixed2 = fixed1
+    with _scope("sssp.lb"):
         C = jnp.where(fixed2, D, C)
 
     f_idx, f_cnt = state.f_idx, state.f_cnt
@@ -597,15 +638,18 @@ def _round(g: Graph, cfg: SSSPConfig, state: SSSPState,
         # a warm unfix-refix, which always moves D).  Repeats the dense
         # path would re-send are value-identical and min-folded — skipping
         # them is bitwise-neutral.
-        if cfg.label_correcting:
-            fresh = D != state.D
-        else:
-            fresh = fixed2 & (~state.fixed | (D != state.D))
-        f_idx, f_cnt = _compact_frontier(fresh, prims.frontier_cap, g.n)
-    return SSSPState(
-        D=D, C=C, fixed=fixed2, explored=explored, round=state.round + 1,
-        fixed_by=state.fixed_by + jnp.stack(rule_counts),
-        f_idx=f_idx, f_cnt=f_cnt, edges=edges)
+        with _scope("sssp.frontier"):
+            if cfg.label_correcting:
+                fresh = D != state.D
+            else:
+                fresh = fixed2 & (~state.fixed | (D != state.D))
+            f_idx, f_cnt = _compact_frontier(fresh, prims.frontier_cap, g.n)
+    with _scope("sssp.count"):
+        return SSSPState(
+            D=D, C=C, fixed=fixed2, explored=explored,
+            round=state.round + 1,
+            fixed_by=state.fixed_by + jnp.stack(rule_counts),
+            f_idx=f_idx, f_cnt=f_cnt, edges=edges)
 
 
 def _chunked_apply(apply_chunk, idx: jax.Array, cnt: jax.Array, cap: int,
@@ -679,151 +723,163 @@ def _round_shared(g: Graph, cfg: SSSPConfig, state: SSSPState,
     D, C, fixed = state.D, state.C, state.fixed          # [B, n]
     cap = prims.frontier_cap
     B = D.shape[0]
-    if cfg.label_correcting:
-        relax_src = D < INF
-    else:
-        relax_src = fixed
 
     # --- Step 1: shared-buffer D relaxation --------------------------
-    if cap >= g.n:
-        overflow = jnp.bool_(False)
-        D_relax = prims.relax_frontier_b(D, f_idx, relax_src)
-    else:
-        overflow = f_cnt > cap     # scalar: a real branch under batching
-        D_relax = jax.lax.cond(
-            overflow,
-            lambda: jax.vmap(prims.relax)(D, relax_src),
-            lambda: prims.relax_frontier_b(D, f_idx, relax_src))
-    u = jnp.minimum(f_idx, g.n - 1)
-    deg = jnp.where(relax_src, g.out_deg[None, :], 0)[:, u]
-    sparse_edges = jnp.sum(jnp.where((f_idx < g.n)[None, :], deg, 0),
-                           axis=1, dtype=jnp.int32)
-    edges = state.edges + jnp.where(overflow, jnp.int32(g.e_pad),
-                                    sparse_edges)
+    with _scope("sssp.relax"):
+        if cfg.label_correcting:
+            relax_src = D < INF
+        else:
+            relax_src = fixed
+        if cap >= g.n:
+            overflow = jnp.bool_(False)
+            D_relax = prims.relax_frontier_b(D, f_idx, relax_src)
+        else:
+            overflow = f_cnt > cap  # scalar: a real branch under batching
+            D_relax = jax.lax.cond(
+                overflow,
+                lambda: jax.vmap(prims.relax)(D, relax_src),
+                lambda: prims.relax_frontier_b(D, f_idx, relax_src))
+    with _scope("sssp.count"):
+        u = jnp.minimum(f_idx, g.n - 1)
+        deg = jnp.where(relax_src, g.out_deg[None, :], 0)[:, u]
+        sparse_edges = jnp.sum(jnp.where((f_idx < g.n)[None, :], deg, 0),
+                               axis=1, dtype=jnp.int32)
+        edges = state.edges + jnp.where(overflow, jnp.int32(g.e_pad),
+                                        sparse_edges)
 
     in_w_nf = state.in_w_nf   # invariant: == in_weight_nf(~round-start fixed)
     cfix_stale = state.cfix_stale
     if warm:
-        improved = fixed & (D_relax < D)
-        fixed = fixed & ~improved
-        C = jnp.where(improved, 0.0, C)
-        if cfix_stale is not None:
-            # an un-fixed vertex leaves the fixed-source set (and its D
-            # is about to drop): its out-neighbours' c_fix is stale.
-            cfix_stale = cfix_stale | improved
-    D = jnp.where(~fixed, jnp.minimum(D, D_relax), D)
+        with _scope("sssp.fix"):
+            improved = fixed & (D_relax < D)
+            fixed = fixed & ~improved
+            C = jnp.where(improved, 0.0, C)
+            if cfix_stale is not None:
+                # an un-fixed vertex leaves the fixed-source set (and its
+                # D is about to drop): its out-neighbours' c_fix is stale.
+                cfix_stale = cfix_stale | improved
+    with _scope("sssp.relax"):
+        D = jnp.where(~fixed, jnp.minimum(D, D_relax), D)
     explored = fixed
 
-    discovered = D < INF
-    active = discovered & ~fixed
-
     # --- Step 2: per-lane reductions + fixing rules ------------------
-    minD = jax.vmap(prims.masked_min)(D, active)          # [B]
-    new_fix = jnp.zeros_like(fixed)
-    rule_counts = []
+    with _scope("sssp.fix"):
+        discovered = D < INF
+        active = discovered & ~fixed
+        minD = jax.vmap(prims.masked_min)(D, active)          # [B]
+        new_fix = jnp.zeros_like(fixed)
+        rule_counts = []
 
-    def count(mask):
-        rule_counts.append(jnp.sum(mask & active & ~new_fix, axis=1,
-                                   dtype=jnp.int32))
-        return mask
+        def count(mask):
+            rule_counts.append(jnp.sum(mask & active & ~new_fix, axis=1,
+                                       dtype=jnp.int32))
+            return mask
 
-    if "min" in cfg.rules:
-        new_fix = new_fix | count(active & (D <= minD[:, None]))
-    else:
-        rule_counts.append(jnp.zeros((B,), jnp.int32))
-    if "pred" in cfg.rules:
-        has_nf_pred = ~jnp.isinf(in_w_nf)
-        new_fix = new_fix | count(active & ~has_nf_pred)
-    else:
-        rule_counts.append(jnp.zeros((B,), jnp.int32))
-    if "in" in cfg.rules:
-        new_fix = new_fix | count(active & (D <= minD[:, None] + in_w_nf))
-    else:
-        rule_counts.append(jnp.zeros((B,), jnp.int32))
-    if "out" in cfg.rules:
-        threshold = jax.vmap(prims.masked_min)(
-            D + g.out_weight[None, :], active)
-        new_fix = new_fix | count(active & (D <= threshold[:, None]))
-    else:
-        rule_counts.append(jnp.zeros((B,), jnp.int32))
+        if "min" in cfg.rules:
+            new_fix = new_fix | count(active & (D <= minD[:, None]))
+        else:
+            rule_counts.append(jnp.zeros((B,), jnp.int32))
+        if "pred" in cfg.rules:
+            has_nf_pred = ~jnp.isinf(in_w_nf)
+            new_fix = new_fix | count(active & ~has_nf_pred)
+        else:
+            rule_counts.append(jnp.zeros((B,), jnp.int32))
+        if "in" in cfg.rules:
+            new_fix = new_fix | count(active
+                                      & (D <= minD[:, None] + in_w_nf))
+        else:
+            rule_counts.append(jnp.zeros((B,), jnp.int32))
+        if "out" in cfg.rules:
+            threshold = jax.vmap(prims.masked_min)(
+                D + g.out_weight[None, :], active)
+            new_fix = new_fix | count(active & (D <= threshold[:, None]))
+        else:
+            rule_counts.append(jnp.zeros((B,), jnp.int32))
 
-    fixed1 = fixed | new_fix
+        fixed1 = fixed | new_fix
 
     # --- Step 3: cone-bounded C update (Lemma 7 lift + Eqn (1)) ------
     if "lb" in cfg.rules:
-        # (a) c_fix maintenance: recompute at out-neighbours of every
-        # source whose fixed bit flipped since the last maintenance.
-        stale_src = cfix_stale | new_fix
-        s_idx, s_cnt = _compact_frontier(
-            jnp.any(stale_src, axis=0), g.n, g.n)
-        c_fix = state.c_fix
+        with _scope("sssp.lb"):
+            # (a) c_fix maintenance: recompute at out-neighbours of every
+            # source whose fixed bit flipped since the last maintenance.
+            stale_src = cfix_stale | new_fix
+            s_idx, s_cnt = _compact_frontier(
+                jnp.any(stale_src, axis=0), g.n, g.n)
+            c_fix = state.c_fix
 
-        def cfix_chunk(chunk, cf):
-            tgts = prims.out_nbrs(chunk)            # [cap, max_out]
-            vals = prims.in_min_at(D, tgts, fixed1)  # [B, cap, max_out]
-            return cf.at[:, tgts].set(vals, mode="drop")
+            def cfix_chunk(chunk, cf):
+                tgts = prims.out_nbrs(chunk)            # [cap, max_out]
+                vals = prims.in_min_at(D, tgts, fixed1)  # [B, cap, max_out]
+                return cf.at[:, tgts].set(vals, mode="drop")
 
-        c_fix = _chunked_apply(cfix_chunk, s_idx, s_cnt, cap, c_fix)
+            c_fix = _chunked_apply(cfix_chunk, s_idx, s_cnt, cap, c_fix)
 
-        # (b) lift, then propagate lower bounds through the cone only
-        C = jnp.where(fixed1, D, jnp.maximum(C, minD[:, None]))
-        for _ in range(cfg.c_prop_iters):
-            prop_src = stale_src | (~fixed1 & (C > minD[:, None]))
-            p_idx, p_cnt = _compact_frontier(
-                jnp.any(prop_src, axis=0), g.n, g.n)
-            # non-cone closed form (exact off the cone — see docstring)
-            base = jnp.minimum(c_fix, minD[:, None] + in_w_nf)
-            C_new = jnp.where(~fixed1, jnp.maximum(C, base), C)
-            C_pre = C
+            # (b) lift, then propagate lower bounds through the cone only
+            C = jnp.where(fixed1, D, jnp.maximum(C, minD[:, None]))
+            for _ in range(cfg.c_prop_iters):
+                prop_src = stale_src | (~fixed1 & (C > minD[:, None]))
+                p_idx, p_cnt = _compact_frontier(
+                    jnp.any(prop_src, axis=0), g.n, g.n)
+                # non-cone closed form (exact off the cone — see docstring)
+                base = jnp.minimum(c_fix, minD[:, None] + in_w_nf)
+                C_new = jnp.where(~fixed1, jnp.maximum(C, base), C)
+                C_pre = C
 
-            def prop_chunk(chunk, cn, C_pre=C_pre):
-                tgts = prims.out_nbrs(chunk)
-                cin = prims.in_min_at(C_pre, tgts, None)  # all sources
-                tc = jnp.minimum(tgts, g.n - 1)
-                cur = C_pre[:, tc]
-                nf = jnp.where(fixed1, 0, 1)[:, tc] > 0   # no bool gather
-                upd = nf & (tgts < g.n)[None]
-                val = jnp.where(upd, jnp.maximum(cur, cin), cur)
-                return cn.at[:, tgts].set(val, mode="drop")
+                def prop_chunk(chunk, cn, C_pre=C_pre):
+                    tgts = prims.out_nbrs(chunk)
+                    cin = prims.in_min_at(C_pre, tgts, None)  # all sources
+                    tc = jnp.minimum(tgts, g.n - 1)
+                    cur = C_pre[:, tc]
+                    nf = jnp.where(fixed1, 0, 1)[:, tc] > 0  # no bool gather
+                    upd = nf & (tgts < g.n)[None]
+                    val = jnp.where(upd, jnp.maximum(cur, cin), cur)
+                    return cn.at[:, tgts].set(val, mode="drop")
 
-            C = _chunked_apply(prop_chunk, p_idx, p_cnt, cap, C_new)
+                C = _chunked_apply(prop_chunk, p_idx, p_cnt, cap, C_new)
 
-        fix_lb = ~fixed1 & discovered & (C >= D)
-        rule_counts.append(jnp.sum(fix_lb, axis=1, dtype=jnp.int32))
-        fixed2 = fixed1 | fix_lb
-        C = jnp.where(fixed2, D, C)
+        with _scope("sssp.fix"):
+            fix_lb = ~fixed1 & discovered & (C >= D)
+            rule_counts.append(jnp.sum(fix_lb, axis=1, dtype=jnp.int32))
+            fixed2 = fixed1 | fix_lb
+        with _scope("sssp.lb"):
+            C = jnp.where(fixed2, D, C)
         cfix_stale = fix_lb   # applied at the NEXT round's maintenance
     else:
         rule_counts.append(jnp.zeros((B,), jnp.int32))
         fixed2 = fixed1
-        C = jnp.where(fixed2, D, C)
+        with _scope("sssp.lb"):
+            C = jnp.where(fixed2, D, C)
         c_fix = state.c_fix
 
     # --- incremental inWeight_nf refresh (restores the invariant for
     # the next round's round-start fixed = fixed2) --------------------
     if in_w_nf is not None:
-        stale2 = state.fixed ^ fixed2     # every bit flip this round
-        w_idx, w_cnt = _compact_frontier(
-            jnp.any(stale2, axis=0), g.n, g.n)
+        with _scope("sssp.inw"):
+            stale2 = state.fixed ^ fixed2     # every bit flip this round
+            w_idx, w_cnt = _compact_frontier(
+                jnp.any(stale2, axis=0), g.n, g.n)
 
-        def inw_chunk(chunk, iw):
-            tgts = prims.out_nbrs(chunk)
-            vals = prims.in_min_at(None, tgts, ~fixed2)   # min weight
-            return iw.at[:, tgts].set(vals, mode="drop")
+            def inw_chunk(chunk, iw):
+                tgts = prims.out_nbrs(chunk)
+                vals = prims.in_min_at(None, tgts, ~fixed2)   # min weight
+                return iw.at[:, tgts].set(vals, mode="drop")
 
-        in_w_nf = _chunked_apply(inw_chunk, w_idx, w_cnt, cap, in_w_nf)
+            in_w_nf = _chunked_apply(inw_chunk, w_idx, w_cnt, cap, in_w_nf)
 
     # --- next-round frontier mask (same freshness law as ``_round``) -
-    if cfg.label_correcting:
-        fresh = D != state.D
-    else:
-        fresh = fixed2 & (~state.fixed | (D != state.D))
-    new_state = SSSPState(
-        D=D, C=C, fixed=fixed2, explored=explored,
-        round=state.round + 1,
-        fixed_by=state.fixed_by + jnp.stack(rule_counts, axis=-1),
-        f_idx=None, f_cnt=None, edges=edges,
-        in_w_nf=in_w_nf, c_fix=c_fix, cfix_stale=cfix_stale)
+    with _scope("sssp.frontier"):
+        if cfg.label_correcting:
+            fresh = D != state.D
+        else:
+            fresh = fixed2 & (~state.fixed | (D != state.D))
+    with _scope("sssp.count"):
+        new_state = SSSPState(
+            D=D, C=C, fixed=fixed2, explored=explored,
+            round=state.round + 1,
+            fixed_by=state.fixed_by + jnp.stack(rule_counts, axis=-1),
+            f_idx=None, f_cnt=None, edges=edges,
+            in_w_nf=in_w_nf, c_fix=c_fix, cfix_stale=cfix_stale)
     return new_state, fresh
 
 
@@ -836,13 +892,16 @@ def _attach_carries(g: Graph, cfg: SSSPConfig, prims, state: SSSPState):
     need_inw = (("in" in cfg.rules) or ("pred" in cfg.rules)
                 or ("lb" in cfg.rules))
     use_lb = "lb" in cfg.rules
-    in_w_nf = jax.vmap(prims.in_weight_nf)(~state.fixed) if need_inw else None
-    c_fix = jax.vmap(prims.relax)(state.D, state.fixed) if use_lb else None
-    cfix_stale = jnp.zeros_like(state.fixed) if use_lb else None
-    return dataclasses.replace(
-        state, f_idx=None, f_cnt=None,
-        edges=jnp.zeros((B,), jnp.int32),
-        in_w_nf=in_w_nf, c_fix=c_fix, cfix_stale=cfix_stale)
+    with _scope("sssp.init"):
+        in_w_nf = (jax.vmap(prims.in_weight_nf)(~state.fixed) if need_inw
+                   else None)
+        c_fix = (jax.vmap(prims.relax)(state.D, state.fixed) if use_lb
+                 else None)
+        cfix_stale = jnp.zeros_like(state.fixed) if use_lb else None
+        return dataclasses.replace(
+            state, f_idx=None, f_cnt=None,
+            edges=jnp.zeros((B,), jnp.int32),
+            in_w_nf=in_w_nf, c_fix=c_fix, cfix_stale=cfix_stale)
 
 
 def _strip_carries(state: SSSPState) -> SSSPState:
@@ -867,20 +926,23 @@ def _frontier_fixpoint(g: Graph, cfg: SSSPConfig, prims,
     cap = prims.frontier_cap
 
     def lane_go(st):
-        active = (st.D < INF) & ~st.fixed
-        pending = st.fixed & ~st.explored
-        go = ((jnp.any(active, axis=1) | jnp.any(pending, axis=1))
-              & (st.round < max_rounds))
-        if targets is not None:
-            t = jnp.maximum(targets, 0)
-            settled = jnp.where(st.fixed & st.explored, 1, 0)  # no bool
-            t_done = (targets >= 0) & (settled[jnp.arange(B), t] > 0)
-            go = go & ~t_done
-        return go
+        with _scope("sssp.freeze"):
+            active = (st.D < INF) & ~st.fixed
+            pending = st.fixed & ~st.explored
+            go = ((jnp.any(active, axis=1) | jnp.any(pending, axis=1))
+                  & (st.round < max_rounds))
+            if targets is not None:
+                t = jnp.maximum(targets, 0)
+                settled = jnp.where(st.fixed & st.explored, 1, 0)  # no bool
+                t_done = (targets >= 0) & (settled[jnp.arange(B), t] > 0)
+                go = go & ~t_done
+            return go
 
     def cond(carry):
         st, _, _ = carry
-        return jnp.any(lane_go(st))
+        go = lane_go(st)
+        with _scope("sssp.freeze"):
+            return jnp.any(go)
 
     def body(carry):
         st, fi, fc = carry
@@ -891,9 +953,11 @@ def _frontier_fixpoint(g: Graph, cfg: SSSPConfig, prims,
             keep = go.reshape((B,) + (1,) * (new.ndim - 1))
             return jnp.where(keep, new, old)
 
-        st3 = jax.tree.map(sel, st2, st)
-        union = jnp.any(fresh & go[:, None], axis=0)
-        nfi, nfc = _compact_frontier(union, cap, g.n)
+        with _scope("sssp.freeze"):
+            st3 = jax.tree.map(sel, st2, st)
+        with _scope("sssp.frontier"):
+            union = jnp.any(fresh & go[:, None], axis=0)
+            nfi, nfc = _compact_frontier(union, cap, g.n)
         return st3, nfi, nfc
 
     state, _, _ = jax.lax.while_loop(cond, body, (state, f_idx, f_cnt))
@@ -917,8 +981,9 @@ def _solve_frontier(g: Graph, cfg: SSSPConfig, sources: jax.Array,
     else:
         state = jax.vmap(lambda s, c: _init_state(g, s, c))(sources, C0)
     state = _attach_carries(g, cfg, prims, state)
-    src_mask = jnp.zeros((g.n,), bool).at[sources].set(True)
-    f_idx, f_cnt = _compact_frontier(src_mask, cap, g.n)
+    with _scope("sssp.init"):
+        src_mask = jnp.zeros((g.n,), bool).at[sources].set(True)
+        f_idx, f_cnt = _compact_frontier(src_mask, cap, g.n)
     max_rounds = cfg.max_rounds or g.n + 2
     tgt = targets if cfg.early_exit else None
     state = _frontier_fixpoint(g, cfg, prims, state, f_idx, f_cnt,
@@ -947,10 +1012,11 @@ def _solve_warm_frontier(g: Graph, cfg: SSSPConfig, prev_D, prev_fixed,
     state, sweeps, taint = jax.vmap(init_one)(
         prev_D, prev_fixed, seeds, pure_increase)
     state = _attach_carries(g, cfg, prims, state)
-    seed = jax.vmap(
-        lambda t, f, d: _warm_seed_mask(g, t, f, d, dec_src))(
-            taint, state.fixed, state.D)
-    f_idx, f_cnt = _compact_frontier(jnp.any(seed, axis=0), cap, g.n)
+    with _scope("sssp.taint"):
+        seed = jax.vmap(
+            lambda t, f, d: _warm_seed_mask(g, t, f, d, dec_src))(
+                taint, state.fixed, state.D)
+        f_idx, f_cnt = _compact_frontier(jnp.any(seed, axis=0), cap, g.n)
     max_rounds = (2 * cfg.max_rounds) if cfg.max_rounds else 2 * g.n + 4
     state = _frontier_fixpoint(g, cfg, prims, state, f_idx, f_cnt,
                                max_rounds, warm=True)
@@ -965,17 +1031,19 @@ def _cond(state: SSSPState, max_rounds: int, target=None):
     D), the remaining rounds can no longer change dist[target] — stop.
     An unreachable target is never discovered, so the loop falls back to
     the normal drain-to-fixpoint termination."""
-    active = (state.D < INF) & ~state.fixed
-    pending = state.fixed & ~state.explored  # fixed but not yet relaxed
-    go = (jnp.any(active) | jnp.any(pending)) & (state.round < max_rounds)
-    if target is not None:
-        t = jnp.maximum(target, 0)           # clamp sentinel for the gather
-        # an int32 gather: bool gathers under vmap miscompiled on TPU
-        # v5e (backends.segment_prims)
-        settled = jnp.where(state.fixed & state.explored, 1, 0)
-        t_done = (target >= 0) & (settled[t] > 0)
-        go = go & ~t_done
-    return go
+    with _scope("sssp.freeze"):
+        active = (state.D < INF) & ~state.fixed
+        pending = state.fixed & ~state.explored  # fixed but not yet relaxed
+        go = ((jnp.any(active) | jnp.any(pending))
+              & (state.round < max_rounds))
+        if target is not None:
+            t = jnp.maximum(target, 0)       # clamp sentinel for the gather
+            # an int32 gather: bool gathers under vmap miscompiled on TPU
+            # v5e (backends.segment_prims)
+            settled = jnp.where(state.fixed & state.explored, 1, 0)
+            t_done = (target >= 0) & (settled[t] > 0)
+            go = go & ~t_done
+        return go
 
 
 def _solve(g: Graph, cfg: SSSPConfig, source,

@@ -47,6 +47,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.graph import Graph, HostGraph
+from repro.core.sssp import spans
 from repro.core.sssp.engine import SP4_CONFIG, SSSPConfig
 from repro.core.sssp.dynamic import DynamicSolver, GraphDelta, make_delta
 
@@ -268,10 +269,9 @@ class LandmarkIndex:
         # by construction, so a graph-version bump can never leave stale
         # host tables feeding the planner's estimates.
         if self._host_tables is None or self._host_tables[0] is not self.d_from:
-            self._host_tables = (
-                self.d_from,
-                np.asarray(self.d_from, np.float64),
-                np.asarray(self.d_to, np.float64))
+            d_from, d_to = spans.fetch((self.d_from, self.d_to))
+            self._host_tables = (self.d_from, d_from.astype(np.float64),
+                                 d_to.astype(np.float64))
         df, dt = self._host_tables[1:]  # [k, n] each
         with np.errstate(invalid="ignore"):
             fwd = df[:, t] - df[:, s]              # [k, B]

@@ -28,7 +28,7 @@ import numpy as np
 from repro.analysis.contracts import contract
 from repro.core.graph import (CsrGraph, EllGraph, Graph, HostGraph,
                               build_ell)
-from repro.core.sssp import backends
+from repro.core.sssp import backends, spans
 from repro.core.sssp.engine import (SP4_CONFIG, SSSPConfig, SSSPResult,
                                     _fixed_by_dict, _solve, _solve_frontier)
 
@@ -300,14 +300,15 @@ class Solver:
               else jnp.asarray(C0, jnp.float32))
         state = self._jit_one(self.graph, self.ell, self.csr,
                               jnp.int32(source), t, c0)
+        rounds, fb, edges = spans.fetch(
+            (state.round, state.fixed_by, state.edges))
         partial = target is not None and self.cfg.early_exit
         return SSSPResult(
             dist=state.D, C=state.C, fixed=state.fixed,
-            rounds=int(state.round), fixed_by=_fixed_by_dict(state.fixed_by),
+            rounds=int(rounds), fixed_by=_fixed_by_dict(fb),
             source=int(source), graph=self.graph,
             target=target, partial=partial,
-            edges_relaxed=None if state.edges is None
-            else int(state.edges))
+            edges_relaxed=None if edges is None else int(edges))
 
     def solve_batch(self, sources, targets=None, C0=None) -> SSSPBatchResult:
         """Distances from B sources via one vmapped program.
@@ -321,52 +322,63 @@ class Solver:
         freezes once its own target is fixed, so the batch runs for the
         max over lanes of the per-lane (early-exited) round counts.
         ``C0`` (float32[B, n], optional) seeds per-lane lower bounds.
+
+        Spans: ``sssp.solve_batch`` (``lanes``) holds ``sssp.prepare``
+        (padding, C0, host->device transfers), ``sssp.launch`` (the
+        compiled call) and ``sssp.fetch`` (the one blocking read of the
+        per-lane counters; distances stay on the device).
         """
         sources = np.asarray(sources, np.int32).ravel()
         if sources.size == 0:
             raise ValueError("solve_batch needs at least one source")
         self._check_sources(sources)
         b = len(sources)
-        b_pad = _next_pow2(b)
-        padded = np.concatenate(
-            [sources, np.full(b_pad - b, sources[-1], np.int32)])
-        if targets is None:
-            tpad = np.full(b_pad, -1, np.int32)
-        else:
-            targets = np.asarray(targets, np.int32).ravel()
-            if targets.size != b:
-                raise ValueError(f"targets {targets.shape} must match "
-                                 f"sources ({b},)")
-            self._check_sources(targets, what="target")
-            # pad with the last lane's target (not -1): an untargeted
-            # padding lane would run to full fixpoint and dominate rounds
-            tpad = np.concatenate(
-                [targets, np.full(b_pad - b, targets[-1], np.int32)])
-        if C0 is None:
-            c0 = jnp.zeros((b_pad, self.graph.n), jnp.float32)
-        else:
-            c0 = jnp.asarray(C0, jnp.float32)
-            if c0.shape != (b, self.graph.n):
-                raise ValueError(f"C0 shape {c0.shape} != "
-                                 f"({b}, {self.graph.n})")
-            if b_pad > b:
-                c0 = jnp.concatenate(
-                    [c0, jnp.broadcast_to(c0[-1:], (b_pad - b,
-                                                    self.graph.n))])
-        if self._sharded_batch is not None:
-            state = self._sharded_batch(padded, self.graph, tpad, c0)
-        else:
-            state = self._jit_batch(self.graph, self.ell, self.csr,
-                                    jnp.asarray(padded),
-                                    jnp.asarray(tpad), c0)
-        fb = np.asarray(state.fixed_by)
+        with spans.span("sssp.solve_batch", lanes=b):
+            with spans.span("sssp.prepare"):
+                b_pad = _next_pow2(b)
+                padded = np.concatenate(
+                    [sources, np.full(b_pad - b, sources[-1], np.int32)])
+                if targets is None:
+                    tpad = np.full(b_pad, -1, np.int32)
+                else:
+                    targets = np.asarray(targets, np.int32).ravel()
+                    if targets.size != b:
+                        raise ValueError(f"targets {targets.shape} must "
+                                         f"match sources ({b},)")
+                    self._check_sources(targets, what="target")
+                    # pad with the last lane's target (not -1): an
+                    # untargeted padding lane would run to full fixpoint
+                    # and dominate rounds
+                    tpad = np.concatenate(
+                        [targets, np.full(b_pad - b, targets[-1], np.int32)])
+                if C0 is None:
+                    c0 = jnp.zeros((b_pad, self.graph.n), jnp.float32)
+                else:
+                    c0 = jnp.asarray(C0, jnp.float32)
+                    if c0.shape != (b, self.graph.n):
+                        raise ValueError(f"C0 shape {c0.shape} != "
+                                         f"({b}, {self.graph.n})")
+                    if b_pad > b:
+                        c0 = jnp.concatenate(
+                            [c0, jnp.broadcast_to(c0[-1:], (b_pad - b,
+                                                            self.graph.n))])
+                if self._sharded_batch is None:
+                    padded, tpad = jnp.asarray(padded), jnp.asarray(tpad)
+            with spans.span("sssp.launch"):
+                if self._sharded_batch is not None:
+                    state = self._sharded_batch(padded, self.graph, tpad,
+                                                c0)
+                else:
+                    state = self._jit_batch(self.graph, self.ell, self.csr,
+                                            padded, tpad, c0)
+            fb, rounds, edges = spans.fetch(
+                (state.fixed_by, state.round, state.edges))
         return SSSPBatchResult(
             sources=sources,
             dist=state.D[:b], C=state.C[:b], fixed=state.fixed[:b],
-            rounds=np.asarray(state.round[:b]),
+            rounds=rounds[:b],
             fixed_by=[_fixed_by_dict(fb[i]) for i in range(b)],
             graph=self.graph,
             targets=None if targets is None else targets,
             partial=targets is not None and self.cfg.early_exit,
-            edges_relaxed=None if state.edges is None
-            else np.asarray(state.edges[:b]))
+            edges_relaxed=None if edges is None else edges[:b])

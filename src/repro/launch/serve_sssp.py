@@ -25,7 +25,9 @@ Query-engine v2: ``--planner`` turns on the cost-based wave planner
 ``--bidirectional`` attaches the meet-in-the-middle point-to-point
 solver, and ``--reselect-threshold T`` re-selects landmark positions
 when observed seed tightness drops below T.  A ``stats`` line reports
-the planner route counts and ``seed_tightness_mean``.
+the planner route counts and ``seed_tightness_mean``; a ``rounds``
+line the engine rounds behind the queries and the deltas and the
+blocking device->host reads per query (``SSSPService.stats``).
 """
 from __future__ import annotations
 
@@ -126,6 +128,11 @@ def main() -> None:
           f"cache hits: {st['cache_hits']}  deltas: {st['deltas']}")
     print(f"  device solve time: {st['solve_seconds']:.2f}s  "
           f"reachable targets: {reachable}/{answered}")
+    print(f"  rounds: {st['query_rounds']} on the query path, "
+          f"{st['delta_rounds']} on deltas ({st['delta_sweeps']} taint "
+          f"sweeps, {st['delta_tainted']} tainted)  host syncs: "
+          f"{st['host_syncs']} ({st['host_syncs'] / max(1, st['queries']):.2f}"
+          f" per query)")
     routes = st["planner_routes"]
     tight = st["seed_tightness_mean"]
     print(f"stats: routes cache={routes['cache']} "

@@ -53,6 +53,7 @@ from collections import OrderedDict
 import numpy as np
 
 from repro.analysis.contracts import contract
+from repro.core.sssp import spans
 from repro.core.sssp.bidirectional import BidirectionalSolver
 from repro.core.sssp.engine import SP4_CONFIG, SSSPConfig, SSSPResult
 from repro.core.sssp.dynamic import DynamicSolver, GraphDelta
@@ -129,6 +130,22 @@ class SSSPService:
         mutated graph (checked after every delta and every served
         wave).  ``None`` keeps re-selection off (metric-only, as
         before).
+
+    Tracing: every wave runs under an ``sssp.serve`` span (``wave``,
+    ``queries``) holding ``sssp.lookup`` (cache probes), ``sssp.plan``,
+    one ``sssp.dispatch`` (``route``, ``lanes``) per solver call and
+    ``sssp.answer``; every delta under ``sssp.apply_delta``
+    (``version``) holding ``sssp.delta.update``, ``sssp.delta.bidi`` and
+    ``sssp.delta.resolve``.  Every span inside carries the wave's or the
+    delta's id, down through the solver facades' ``sssp.solve_batch``,
+    and every blocking device->host read is an ``sssp.fetch``
+    (:mod:`repro.core.sssp.spans`).  Counters in ``stats``, built from
+    values already on the host: ``query_rounds`` (per query-path
+    dispatch, its rounds, the most over its lanes), ``delta_rounds``
+    (per delta, the warm program's rounds plus any cold or pair
+    refresh's), ``delta_sweeps``, ``delta_tainted`` (the largest taint
+    cone per delta) and ``host_syncs`` (``sssp.fetch`` reads while
+    serving).
     """
 
     def __init__(self, graph, cfg: SSSPConfig = SP4_CONFIG,
@@ -187,12 +204,15 @@ class SSSPService:
             self.reselect_policy = reselect
         elif reselect is not None:
             self.reselect_policy = ReselectPolicy(threshold=float(reselect))
+        self._waves = 0
         self.stats = dict(queries=0, batches=0, sources_solved=0,
                           cache_hits=0, solve_seconds=0.0, deltas=0,
-                          delta_seconds=0.0, warm_refreshed=0,
+                          warm_refreshed=0,
                           p2p_solves=0, seed_tightness_mean=None,
                           seed_tightness_count=0, bidi_solves=0,
                           reselects=0, pair_warm_refreshed=0,
+                          query_rounds=0, delta_rounds=0, delta_sweeps=0,
+                          delta_tainted=0, host_syncs=0,
                           planner_routes=dict(cache=0, targeted=0,
                                               bidirectional=0, full=0,
                                               full_vector=0))
@@ -219,7 +239,7 @@ class SSSPService:
             del self._cache[source]
             return None
         if partial:
-            if target is None or not bool(np.asarray(res.fixed[target])):
+            if target is None or not bool(spans.fetch(res.fixed[target])):
                 return None            # keep the entry: other targets may hit
         self._cache.move_to_end(source)
         return res
@@ -265,14 +285,39 @@ class SSSPService:
         for at in range(0, len(missing), self.batch):
             chunk = missing[at: at + self.batch]
             padded = chunk + [chunk[-1]] * (self.batch - len(chunk))
-            t0 = time.perf_counter()
-            batch_res = self.solver.solve_batch(padded)
-            np.asarray(batch_res.dist)  # block: count device time honestly
-            self.stats["solve_seconds"] += time.perf_counter() - t0
+            batch_res, dt = self._dispatch("full", padded, len(chunk))
+            self.stats["solve_seconds"] += dt
             self.stats["batches"] += 1
             for i, s in enumerate(chunk):
                 self._admit(s, batch_res[i])
             self.stats["sources_solved"] += len(chunk)
+
+    def _dispatch(self, route: str, sources: list[int], lanes: int, *,
+                  targets=None, C0=None):
+        """One query-path ``solve_batch`` of ``lanes`` real lanes, run
+        to completion: ``(result, seconds)``, its rounds counted."""
+        with spans.span("sssp.dispatch", route=route, lanes=lanes):
+            t0 = time.perf_counter()
+            batch_res = self.solver.solve_batch(sources, targets=targets,
+                                                C0=C0)
+            spans.fetch(batch_res.dist)  # block: count device time honestly
+            dt = time.perf_counter() - t0
+        self.stats["query_rounds"] += int(np.max(batch_res.rounds))
+        return batch_res, dt
+
+    @staticmethod
+    def _answer(q: Query, res: SSSPResult) -> None:
+        """Fill ``q`` from ``res``: the whole vector, or the target's
+        distance and, where it is reachable, the path."""
+        if q.target is None:
+            q.dist = spans.fetch(res.dist)
+            q.distance = None
+            q.path = None
+        else:
+            q.distance = float(spans.fetch(res.dist[q.target]))
+            q.path = (res.path_to(q.target)
+                      if np.isfinite(q.distance) else None)
+        q.done = True
 
     # ------------------------------------------------------------------
     def apply_delta(self, delta: GraphDelta, *,
@@ -292,6 +337,11 @@ class SSSPService:
         stale and seeding survives only pure-increase deltas.  Returns
         the solver's update stats.
         """
+        with spans.ids(version=self.version + 1), \
+                spans.span("sssp.apply_delta"):
+            return self._apply_delta(delta, refresh_hot)
+
+    def _apply_delta(self, delta: GraphDelta, refresh_hot) -> dict:
         k = self.batch if refresh_hot is None else int(refresh_hot)
         hot: list[int] = []
         if k > 0:   # newest-first walk for the k hottest FULL entries
@@ -313,12 +363,16 @@ class SSSPService:
                 if ver == self.version and lanes is not None:
                     hot_pairs.append((key[0], key[1], lanes[0], lanes[1]))
             hot_pairs.reverse()
-        t0 = time.perf_counter()
         eager_lm = self.landmarks is not None and self.refresh_landmarks
         lms = ([int(v) for v in self.landmarks.landmarks]
                if eager_lm else [])
-        stats = self.solver.update(
-            delta, refresh=list(dict.fromkeys(hot + lms)))
+        with spans.span("sssp.delta.update"):
+            stats = self.solver.update(
+                delta, refresh=list(dict.fromkeys(hot + lms)))
+        self.stats["delta_rounds"] += (max(stats["warm_rounds"], default=0)
+                                       + stats["cold_rounds"])
+        self.stats["delta_sweeps"] += stats["sweeps"]
+        self.stats["delta_tainted"] += max(stats["tainted"], default=0)
         if self.landmarks is not None:
             self.landmarks.apply_delta(delta, refresh=eager_lm)
         if self._bidi is not None:
@@ -327,21 +381,22 @@ class SSSPService:
             # and the hot pairs re-solve warm from their cached lanes,
             # re-admitted fresh (the pair-cache mirror of the hot-source
             # refresh above; the stale tail re-solves lazily).
-            warm_out = self._bidi.update(delta, warm=hot_pairs)
-            for (s, t), r in warm_out.items():
-                self._pair_admit(s, t, r.distance,
-                                 r.path() if np.isfinite(r.distance)
-                                 else None, lanes=(r.D, r.fixed))
-                self._admit(s, r.forward_result(), partial=True)
+            with spans.span("sssp.delta.bidi"):
+                warm_out = self._bidi.update(delta, warm=hot_pairs)
+                for (s, t), r in warm_out.items():
+                    self._pair_admit(s, t, r.distance,
+                                     r.path() if np.isfinite(r.distance)
+                                     else None, lanes=(r.D, r.fixed))
+                    self._admit(s, r.forward_result(), partial=True)
             self.stats["pair_warm_refreshed"] += len(warm_out)
+            self.stats["delta_rounds"] += sum(r.rounds
+                                              for r in warm_out.values())
         if hot:
-            refreshed = self.solver.resolve(hot)  # tracked: no new solves
-            np.asarray(refreshed.dist)
-            for i, s in enumerate(hot):
-                self._admit(int(s), refreshed[i])
-        # delta work gets its own timer: solve_seconds stays consistent
-        # with batches/sources_solved (the query-path counters).
-        self.stats["delta_seconds"] += time.perf_counter() - t0
+            with spans.span("sssp.delta.resolve"):
+                refreshed = self.solver.resolve(hot)  # tracked: no solves
+                spans.fetch(refreshed.dist)
+                for i, s in enumerate(hot):
+                    self._admit(int(s), refreshed[i])
         self.stats["deltas"] += 1
         self.stats["warm_refreshed"] += stats["warm_refreshed"]
         self.stats["sources_solved"] += stats["cold_refreshed"]
@@ -372,6 +427,15 @@ class SSSPService:
         path (targeted early-exit solves, landmark-seeded when an index
         is attached); full-vector queries always take the full path.
         """
+        self._waves += 1
+        f0 = spans.fetches()
+        with spans.ids(wave=self._waves), \
+                spans.span("sssp.serve", queries=len(queries)):
+            self._serve_wave(queries)
+        self.stats["host_syncs"] += spans.fetches() - f0
+        return queries
+
+    def _serve_wave(self, queries: list[Query]) -> None:
         n = self.solver.graph.n
         bad = [q for q in queries
                if not (0 <= q.source < n
@@ -384,8 +448,10 @@ class SSSPService:
                 f"first bad query {bad[0]}")
         if not self.p2p:
             if self.planner is not None:
-                return self._serve_full_planned(queries)
-            return self._serve_full(queries)
+                self._serve_full_planned(queries)
+            else:
+                self._serve_full(queries)
+            return
         full_q = [q for q in queries if q.target is None]
         tgt_q = [q for q in queries if q.target is not None]
         if full_q:
@@ -401,36 +467,29 @@ class SSSPService:
             else:
                 self._serve_p2p(tgt_q)
         self._maybe_reselect()
-        return queries
 
     def _serve_full(self, queries: list[Query]) -> list[Query]:
         """Original path: full solve per (cache-missing) source."""
         # a hit = a query answered without a solve on its behalf: neither
         # the first query of an initially-missing source (it pays for the
         # batch solve) nor an eviction-triggered mid-wave re-solve.
-        misses = {q.source for q in queries
-                  if not self._cached(q.source)}
+        with spans.span("sssp.lookup"):
+            misses = {q.source for q in queries
+                      if not self._cached(q.source)}
         self.stats["queries"] += len(queries)
         self._solve_missing([q.source for q in queries])
         paid = set()   # missing sources whose triggering query is consumed
-        for q in queries:
-            res = self._lookup(q.source)
-            if res is None:  # evicted mid-wave: cache smaller than the wave
-                self._solve_missing([q.source])
+        with spans.span("sssp.answer"):
+            for q in queries:
                 res = self._lookup(q.source)
-            elif q.source in misses and q.source not in paid:
-                paid.add(q.source)
-            else:
-                self.stats["cache_hits"] += 1
-            if q.target is None:
-                q.dist = np.asarray(res.dist)
-                q.distance = None
-                q.path = None
-            else:
-                q.distance = float(np.asarray(res.dist[q.target]))
-                q.path = (res.path_to(q.target)
-                          if np.isfinite(q.distance) else None)
-            q.done = True
+                if res is None:  # evicted mid-wave: cache < the wave
+                    self._solve_missing([q.source])
+                    res = self._lookup(q.source)
+                elif q.source in misses and q.source not in paid:
+                    paid.add(q.source)
+                else:
+                    self.stats["cache_hits"] += 1
+                self._answer(q, res)
         return queries
 
     def _serve_full_planned(self, queries: list[Query]) -> list[Query]:
@@ -442,17 +501,17 @@ class SSSPService:
         identical to :meth:`_serve_full`.
         """
         routes = self.stats["planner_routes"]
-        misses = {q.source for q in queries
-                  if not self._cached(q.source)}
+        with spans.span("sssp.lookup"):
+            misses = {q.source for q in queries
+                      if not self._cached(q.source)}
         self.stats["queries"] += len(queries)
-        for wave in self.planner.plan_full_vector(
-                sorted(misses), batch=self.batch):
+        with spans.span("sssp.plan"):
+            waves = self.planner.plan_full_vector(sorted(misses),
+                                                  batch=self.batch)
+        for wave in waves:
             shape = WavePlanner.wave_shape(len(wave), self.batch)
             padded = wave + [wave[-1]] * (shape - len(wave))
-            t0 = time.perf_counter()
-            batch_res = self.solver.solve_batch(padded)
-            np.asarray(batch_res.dist)  # block: count device time honestly
-            dt = time.perf_counter() - t0
+            batch_res, dt = self._dispatch("full_vector", padded, len(wave))
             self.stats["solve_seconds"] += dt
             self.stats["batches"] += 1
             for i, s in enumerate(wave):
@@ -460,27 +519,20 @@ class SSSPService:
             self.stats["sources_solved"] += len(wave)
             self.planner.observe("full_vector", dt, len(wave))
         paid = set()   # missing sources whose triggering query is consumed
-        for q in queries:
-            res = self._lookup(q.source)
-            if res is None:  # evicted mid-wave: cache smaller than the wave
-                self._solve_missing([q.source])
+        with spans.span("sssp.answer"):
+            for q in queries:
                 res = self._lookup(q.source)
-                routes["full_vector"] += 1
-            elif q.source in misses and q.source not in paid:
-                paid.add(q.source)
-                routes["full_vector"] += 1
-            else:
-                self.stats["cache_hits"] += 1
-                routes["cache"] += 1
-            if q.target is None:
-                q.dist = np.asarray(res.dist)
-                q.distance = None
-                q.path = None
-            else:
-                q.distance = float(np.asarray(res.dist[q.target]))
-                q.path = (res.path_to(q.target)
-                          if np.isfinite(q.distance) else None)
-            q.done = True
+                if res is None:  # evicted mid-wave: cache < the wave
+                    self._solve_missing([q.source])
+                    res = self._lookup(q.source)
+                    routes["full_vector"] += 1
+                elif q.source in misses and q.source not in paid:
+                    paid.add(q.source)
+                    routes["full_vector"] += 1
+                else:
+                    self.stats["cache_hits"] += 1
+                    routes["cache"] += 1
+                self._answer(q, res)
         return queries
 
     def _serve_p2p(self, queries: list[Query]) -> list[Query]:
@@ -497,12 +549,13 @@ class SSSPService:
         self.stats["queries"] += len(queries)
         hits: dict[int, SSSPResult] = {}
         need: list[tuple[int, int]] = []
-        for q in queries:
-            res = self._lookup(q.source, target=q.target)
-            if res is not None:
-                hits[id(q)] = res
-            else:
-                need.append((q.source, q.target))
+        with spans.span("sssp.lookup"):
+            for q in queries:
+                res = self._lookup(q.source, target=q.target)
+                if res is not None:
+                    hits[id(q)] = res
+                else:
+                    need.append((q.source, q.target))
         need = list(dict.fromkeys(need))
         # Per-lane round capping: a vmapped wave runs for the MAX over
         # lanes of the per-lane (early-exited) round counts, so one far
@@ -512,32 +565,31 @@ class SSSPService:
         # round-count order because seeded bounds certify near targets
         # in few rounds).  Stable sort: equal estimates keep FIFO order.
         if self.landmarks is not None and len(need) > 1:
-            est = self.landmarks.estimate_pairs(need)
-            if est is not None:
-                order = np.argsort(est, kind="stable")
-                need = [need[i] for i in order]
+            with spans.span("sssp.plan"):
+                est = self.landmarks.estimate_pairs(need)
+                if est is not None:
+                    order = np.argsort(est, kind="stable")
+                    need = [need[i] for i in order]
         solved: dict[tuple[int, int], SSSPResult] = {}
         for at in range(0, len(need), self.batch):
             chunk = need[at: at + self.batch]
             solved.update(self._targeted_wave(chunk, self.batch))
         paid: set[tuple[int, int]] = set()
-        for q in queries:
-            res = hits.get(id(q))
-            if res is not None:
-                self.stats["cache_hits"] += 1
-            else:
-                res = solved[(q.source, q.target)]
-                # duplicate pairs in one wave: only the first query pays
-                # for the solve, the rest are hits (same definition as
-                # the full path's `paid` accounting)
-                if (q.source, q.target) in paid:
+        with spans.span("sssp.answer"):
+            for q in queries:
+                res = hits.get(id(q))
+                if res is not None:
                     self.stats["cache_hits"] += 1
                 else:
-                    paid.add((q.source, q.target))
-            q.distance = float(np.asarray(res.dist[q.target]))
-            q.path = (res.path_to(q.target)
-                      if np.isfinite(q.distance) else None)
-            q.done = True
+                    res = solved[(q.source, q.target)]
+                    # duplicate pairs in one wave: only the first query
+                    # pays for the solve, the rest are hits (same
+                    # definition as the full path's `paid` accounting)
+                    if (q.source, q.target) in paid:
+                        self.stats["cache_hits"] += 1
+                    else:
+                        paid.add((q.source, q.target))
+                self._answer(q, res)
         return queries
 
     def _targeted_wave(self, chunk: list[tuple[int, int]],
@@ -551,8 +603,8 @@ class SSSPService:
         t0 = time.perf_counter()
         C0 = (self.landmarks.seed_batch(srcs)
               if self.landmarks is not None else None)
-        batch_res = self.solver.solve_batch(srcs, targets=tgts, C0=C0)
-        np.asarray(batch_res.dist)  # block: count device time honestly
+        batch_res, _ = self._dispatch("targeted", srcs, len(chunk),
+                                      targets=tgts, C0=C0)
         dt = time.perf_counter() - t0
         self.stats["solve_seconds"] += dt
         self.stats["batches"] += 1
@@ -580,9 +632,12 @@ class SSSPService:
         t0 = time.perf_counter()
         ratios = []
         for i, (s, t) in enumerate(pairs):
-            r = self._bidi.solve(s, t)
-            ans = (r.distance,
-                   r.path() if np.isfinite(r.distance) else None)
+            with spans.span("sssp.dispatch", route="bidirectional",
+                            lanes=2):
+                r = self._bidi.solve(s, t)
+                ans = (r.distance,
+                       r.path() if np.isfinite(r.distance) else None)
+            self.stats["query_rounds"] += r.rounds
             out[(s, t)] = ans
             self._pair_admit(s, t, ans[0], ans[1], lanes=(r.D, r.fixed))
             self._admit(s, r.forward_result(), partial=True)
@@ -615,23 +670,25 @@ class SSSPService:
         routes = self.stats["planner_routes"]
         hits: dict[int, SSSPResult | tuple[float, list | None]] = {}
         need: list[tuple[int, int]] = []
-        for q in queries:
-            ans = self._pair_lookup(q.source, q.target)
-            if ans is None:
-                ans = self._lookup(q.source, target=q.target)
-            if ans is not None:
-                hits[id(q)] = ans
-            else:
-                need.append((q.source, q.target))
+        with spans.span("sssp.lookup"):
+            for q in queries:
+                ans = self._pair_lookup(q.source, q.target)
+                if ans is None:
+                    ans = self._lookup(q.source, target=q.target)
+                if ans is not None:
+                    hits[id(q)] = ans
+                else:
+                    need.append((q.source, q.target))
         need = list(dict.fromkeys(need))
-        est = (self.landmarks.estimate_pairs(need)
-               if self.landmarks is not None and need else None)
-        if self.planner is not None:
-            plan = self.planner.plan(need, est, batch=self.batch,
-                                     bidi_ok=self._bidi is not None)
-        else:   # bidirectional-only mode: every miss meets in the middle
-            plan = WavePlan(full_sources=[], full_pairs=[],
-                            bidi_pairs=list(need), targeted_waves=[])
+        with spans.span("sssp.plan"):
+            est = (self.landmarks.estimate_pairs(need)
+                   if self.landmarks is not None and need else None)
+            if self.planner is not None:
+                plan = self.planner.plan(need, est, batch=self.batch,
+                                         bidi_ok=self._bidi is not None)
+            else:  # bidirectional-only mode: every miss meets in the middle
+                plan = WavePlan(full_sources=[], full_pairs=[],
+                                bidi_pairs=list(need), targeted_waves=[])
         if plan.full_sources:
             t0 = time.perf_counter()
             self._solve_missing(plan.full_sources)
@@ -650,44 +707,38 @@ class SSSPService:
             solved.update(self._targeted_wave(wave, shape))
         full_keys = set(plan.full_pairs)
         paid: set[tuple[int, int]] = set()
-        for q in queries:
-            key = (q.source, q.target)
-            ans = hits.get(id(q))
-            if ans is not None:
-                routes["cache"] += 1
-                self.stats["cache_hits"] += 1
-                if isinstance(ans, tuple):
-                    q.distance, q.path = ans
-                else:
-                    q.distance = float(np.asarray(ans.dist[q.target]))
-                    q.path = (ans.path_to(q.target)
-                              if np.isfinite(q.distance) else None)
-                q.done = True
-                continue
-            if key in bidi_out:
-                routes["bidirectional"] += 1
-                q.distance, q.path = bidi_out[key]
-            elif key in full_keys:
-                routes["full"] += 1
-                res = self._lookup(q.source)
-                if res is None:   # evicted mid-wave: re-solve on demand
-                    self._solve_missing([q.source])
+        with spans.span("sssp.answer"):
+            for q in queries:
+                key = (q.source, q.target)
+                ans = hits.get(id(q))
+                if ans is not None:
+                    routes["cache"] += 1
+                    self.stats["cache_hits"] += 1
+                    if isinstance(ans, tuple):
+                        q.distance, q.path = ans
+                        q.done = True
+                    else:
+                        self._answer(q, ans)
+                    continue
+                if key in bidi_out:
+                    routes["bidirectional"] += 1
+                    q.distance, q.path = bidi_out[key]
+                    q.done = True
+                elif key in full_keys:
+                    routes["full"] += 1
                     res = self._lookup(q.source)
-                q.distance = float(np.asarray(res.dist[q.target]))
-                q.path = (res.path_to(q.target)
-                          if np.isfinite(q.distance) else None)
-            else:
-                routes["targeted"] += 1
-                res = solved[key]
-                q.distance = float(np.asarray(res.dist[q.target]))
-                q.path = (res.path_to(q.target)
-                          if np.isfinite(q.distance) else None)
-            # duplicate pairs in one wave: only the first query pays
-            if key in paid:
-                self.stats["cache_hits"] += 1
-            else:
-                paid.add(key)
-            q.done = True
+                    if res is None:  # evicted mid-wave: re-solve on demand
+                        self._solve_missing([q.source])
+                        res = self._lookup(q.source)
+                    self._answer(q, res)
+                else:
+                    routes["targeted"] += 1
+                    self._answer(q, solved[key])
+                # duplicate pairs in one wave: only the first query pays
+                if key in paid:
+                    self.stats["cache_hits"] += 1
+                else:
+                    paid.add(key)
         return queries
 
     def _record_tightness(self, C0, batch_res, chunk) -> None:
@@ -698,8 +749,8 @@ class SSSPService:
         ``needs_reselect(threshold)`` turns it into the re-selection
         signal (metric + hook; acting on it stays the operator's call).
         """
-        c0 = np.asarray(C0, np.float64)
-        d = np.asarray(batch_res.dist, np.float64)
+        c0, d = spans.fetch((C0, batch_res.dist))
+        c0, d = c0.astype(np.float64), d.astype(np.float64)
         idx = np.arange(len(chunk))
         tgt = np.asarray([t for _, t in chunk], np.int64)
         dist = d[idx, tgt]
@@ -716,4 +767,4 @@ class SSSPService:
     def distances(self, source: int) -> np.ndarray:
         """Full distance vector for one source (through the cache)."""
         self._solve_missing([source])
-        return np.asarray(self._lookup(source).dist)
+        return spans.fetch(self._lookup(source).dist)
