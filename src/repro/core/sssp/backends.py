@@ -68,11 +68,11 @@ class Primitives:
     #   src_mask[B,n]) -> [B,n]: ONE shared gather of the union
     #   frontier's out-edges, per-lane scatter-min.
     out_nbrs: Callable | None = None  # (idx[cap]) -> int32[cap, max_out]
-    #   shared cone-target table of one maintenance chunk (padding n).
+    #   shared target table of one inWeight_nf refresh chunk (padding n).
     in_min_at: Callable | None = None  # (x[B,n]|None, tgt, mask[B,n]|None)
     #   -> [B, *tgt.shape]: full in-neighbourhood masked min per target
-    #   over the CSC view — the incremental inWeight_nf / c_fix /
-    #   Eqn-(1) recompute primitive.
+    #   over the CSC view — the incremental inWeight_nf recompute
+    #   primitive.
 
 
 def _masked_min_local(x: jax.Array, mask: jax.Array) -> jax.Array:
@@ -154,17 +154,19 @@ def ell_prims(g: Graph, ell: EllGraph, use_pallas: bool) -> Primitives:
     "backend.frontier",
     routes=("frontier.*",),
     require=("cumsum", "scatter-min"),
-    dense_budget={"frontier.cold": 2, "frontier.targeted": 2,
-                  "frontier.batched": 2, "frontier.warm": 4},
+    dense_budget={"frontier.cold": 4, "frontier.targeted": 4,
+                  "frontier.batched": 4, "frontier.warm": 6},
     notes="The whole point of this backend is the compacted sparse "
           "relax: the program must contain the cumsum frontier "
           "compaction AND the scatter-min relax — on EVERY route, "
           "batched and warm included (the shared batch frontier of "
           "engine._round_shared; the old dense-under-vmap waiver is "
-          "retired).  The budgets count only the step-1 dense-relax "
-          "fallback branch and the warm taint sweep: inWeight_nf and "
-          "C-propagation are incremental chunked updates with NO dense "
-          "rebuild anywhere in the compiled program "
+          "retired).  The budgets count the step-1 dense-relax "
+          "fallback branch (2), the Eqn-(1) C-propagation sweep (2: "
+          "after the Lemma-7 lift nearly every unfixed vertex's C can "
+          "move, so a wavefront-bounded walk covers more slots than "
+          "one dense sweep) and the warm taint sweep (2).  inWeight_nf "
+          "stays an incremental chunked update with no dense rebuild "
           "(docs/round-anatomy.md).")
 def frontier_prims(g: Graph, csr: CsrGraph, cap: int,
                    use_pallas: bool = False) -> Primitives:
@@ -176,11 +178,11 @@ def frontier_prims(g: Graph, csr: CsrGraph, cap: int,
     (kernels/frontier_relax) when ``use_pallas``, the jnp oracle
     otherwise.  The batched hooks (``relax_frontier_b`` / ``out_nbrs``
     / ``in_min_at``) switch the engine to ``_round_shared``: one UNION
-    frontier per batch, incremental inWeight_nf and cone-bounded
-    C-propagation over the CSC run table — every pass
-    wavefront-proportional.  The dense segment primitives remain as the
-    step-1 overflow fallback and the init-region seeds, which keeps
-    every round bitwise-identical to the segment backend.
+    frontier per batch and incremental inWeight_nf over the CSC run
+    table.  The dense segment primitives remain as the step-1 overflow
+    fallback, the Eqn-(1) C-propagation sweep and the init-region
+    seeds, which keeps every round bitwise-identical to the segment
+    backend.
     """
     from repro.kernels import ops
 

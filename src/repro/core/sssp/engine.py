@@ -41,7 +41,7 @@ the outermost ``sssp.*`` named scope in its ``op_name`` metadata, so a
 profiler trace can sum device time by pass (docs/round-anatomy.md):
 
   sssp.relax     step-1 D relaxation (dense fallback and ``relax2``)
-  sssp.lb        step 3: Lemma-7 lift, Eqn (1), the c_fix/cone walks
+  sssp.lb        step 3: Lemma-7 lift and the Eqn-(1) sweep
   sssp.inw       inWeight_nf, dense or the incremental refresh
   sssp.fix       step-2 reductions and every fixing/un-fixing rule
   sssp.frontier  next-round fresh mask and its compaction
@@ -128,12 +128,6 @@ class SSSPState:
     #   inWeight_nf: min in-edge weight over NON-fixed sources, valid for
     #   this round-start ``fixed``; refreshed end-of-round only at the
     #   out-neighbourhoods of vertices whose fixed bit flipped.
-    c_fix: jax.Array | None = None  # float32[B, n] min over FIXED
-    #   in-sources u of D[u] + w — the fixed-source half of the Eqn-(1)
-    #   C-propagation input, maintained over the same flip cones.
-    cfix_stale: jax.Array | None = None  # bool[B, n] sources whose fixed
-    #   bit flipped AFTER the last c_fix maintenance (lb fixes of the
-    #   previous round; warm un-fixes join at the next round's step 1).
 
 
 @dataclasses.dataclass
@@ -487,7 +481,7 @@ def _round(g: Graph, cfg: SSSPConfig, state: SSSPState,
     the round directly over their own lanes (bidirectional.py's two-lane
     program, whose ``cap >= n`` keeps the sparse branch static).  Every
     Solver/Dynamic/Fleet frontier route instead takes ``_round_shared``
-    below, where those passes are wavefront-proportional too (see
+    below, where inWeight_nf is an incremental carry too (see
     docs/round-anatomy.md).
     """
     if prims is None:
@@ -658,13 +652,13 @@ def _chunked_apply(apply_chunk, idx: jax.Array, cnt: jax.Array, cap: int,
     ``cap``-sized chunks of a full compacted index list ``idx``
     (int32[n], padding n) until ``cnt`` entries are consumed.
 
-    This is how the incremental inWeight_nf / c_fix / cone-propagation
-    updates stay wavefront-proportional WITHOUT a dense fallback branch:
-    a round pays ``ceil(cnt / cap)`` chunk sweeps under a
-    ``lax.while_loop`` — never a full-``e_pad`` pass, and no dense
-    rebuild ever appears in the compiled program.  Chunks partition the
-    target set, and every chunk's updates are full recomputes at its
-    targets (order-independent), so chunking is bitwise-neutral.
+    This is how the incremental inWeight_nf refresh stays
+    wavefront-proportional WITHOUT a dense fallback branch: a round pays
+    ``ceil(cnt / cap)`` chunk sweeps under a ``lax.while_loop`` — never
+    a full-``e_pad`` pass.  Each chunk costs its full ``cap`` width
+    however few of its entries are live.  Chunks partition the target
+    set, and every chunk's updates are full recomputes at its targets
+    (order-independent), so chunking is bitwise-neutral.
     """
     n = idx.shape[0]
     idx_pad = jnp.concatenate([idx, jnp.full((cap,), n, idx.dtype)])
@@ -704,16 +698,15 @@ def _round_shared(g: Graph, cfg: SSSPConfig, state: SSSPState,
       only at out-neighbours of vertices whose fixed bit flipped
       (full in-neighbourhood recompute per target via ``prims.in_min_at``
       — a min is order-independent, so recompute-at-a-superset is exact).
-    * **C-propagation** is cone-bounded: ``c_fix`` carries the
-      fixed-source half ``min_{u fixed} D[u] + w``; non-cone vertices
-      get the closed form ``max(C, min(c_fix, minD + inWeight_nf))``
-      (their non-fixed in-sources all sit exactly at ``C == minD`` after
-      the Lemma-7 lift, and their in-sources' fixed bits are unchanged —
-      both guaranteed by routing every violator through the cone), and
-      cone vertices — out-neighbours of flipped-bit sources and of
-      sources with ``C > minD`` — get a full Eqn-(1) recompute.
-    * The three maintenance sweeps run through ``_chunked_apply``:
-      wavefront-proportional with NO dense branch in the program at all.
+    * **C-propagation** is one dense Eqn-(1) sweep per
+      ``c_prop_iters`` iteration, the line ``_round`` runs, vmapped over
+      lanes.  A wavefront bound buys nothing here: the Lemma-7 lift puts
+      every unfixed C at ``minD`` or above, and Eqn (1) then lifts every
+      unfixed vertex whose in-sources are all unfixed above ``minD``
+      (w > 0), so the vertices whose C can still move cover nearly the
+      whole unfixed set in every round (docs/round-anatomy.md §4).
+    * The inWeight_nf refresh runs through ``_chunked_apply``:
+      wavefront-proportional with no dense branch.
 
     Returns ``(state, fresh)`` with ``fresh`` the per-lane bool[B, n]
     next-round frontier mask; the driver unions it, compacts once, and
@@ -748,16 +741,11 @@ def _round_shared(g: Graph, cfg: SSSPConfig, state: SSSPState,
                                         sparse_edges)
 
     in_w_nf = state.in_w_nf   # invariant: == in_weight_nf(~round-start fixed)
-    cfix_stale = state.cfix_stale
     if warm:
         with _scope("sssp.fix"):
             improved = fixed & (D_relax < D)
             fixed = fixed & ~improved
             C = jnp.where(improved, 0.0, C)
-            if cfix_stale is not None:
-                # an un-fixed vertex leaves the fixed-source set (and its
-                # D is about to drop): its out-neighbours' c_fix is stale.
-                cfix_stale = cfix_stale | improved
     with _scope("sssp.relax"):
         D = jnp.where(~fixed, jnp.minimum(D, D_relax), D)
     explored = fixed
@@ -798,59 +786,23 @@ def _round_shared(g: Graph, cfg: SSSPConfig, state: SSSPState,
 
         fixed1 = fixed | new_fix
 
-    # --- Step 3: cone-bounded C update (Lemma 7 lift + Eqn (1)) ------
+    # --- Step 3: C update (Lemma 7 lift, then Eqn (1) as dense sweeps) -
     if "lb" in cfg.rules:
         with _scope("sssp.lb"):
-            # (a) c_fix maintenance: recompute at out-neighbours of every
-            # source whose fixed bit flipped since the last maintenance.
-            stale_src = cfix_stale | new_fix
-            s_idx, s_cnt = _compact_frontier(
-                jnp.any(stale_src, axis=0), g.n, g.n)
-            c_fix = state.c_fix
-
-            def cfix_chunk(chunk, cf):
-                tgts = prims.out_nbrs(chunk)            # [cap, max_out]
-                vals = prims.in_min_at(D, tgts, fixed1)  # [B, cap, max_out]
-                return cf.at[:, tgts].set(vals, mode="drop")
-
-            c_fix = _chunked_apply(cfix_chunk, s_idx, s_cnt, cap, c_fix)
-
-            # (b) lift, then propagate lower bounds through the cone only
             C = jnp.where(fixed1, D, jnp.maximum(C, minD[:, None]))
+            all_src = jnp.ones_like(fixed)
             for _ in range(cfg.c_prop_iters):
-                prop_src = stale_src | (~fixed1 & (C > minD[:, None]))
-                p_idx, p_cnt = _compact_frontier(
-                    jnp.any(prop_src, axis=0), g.n, g.n)
-                # non-cone closed form (exact off the cone — see docstring)
-                base = jnp.minimum(c_fix, minD[:, None] + in_w_nf)
-                C_new = jnp.where(~fixed1, jnp.maximum(C, base), C)
-                C_pre = C
-
-                def prop_chunk(chunk, cn, C_pre=C_pre):
-                    tgts = prims.out_nbrs(chunk)
-                    cin = prims.in_min_at(C_pre, tgts, None)  # all sources
-                    tc = jnp.minimum(tgts, g.n - 1)
-                    cur = C_pre[:, tc]
-                    nf = jnp.where(fixed1, 0, 1)[:, tc] > 0  # no bool gather
-                    upd = nf & (tgts < g.n)[None]
-                    val = jnp.where(upd, jnp.maximum(cur, cin), cur)
-                    return cn.at[:, tgts].set(val, mode="drop")
-
-                C = _chunked_apply(prop_chunk, p_idx, p_cnt, cap, C_new)
-
+                c_in = jax.vmap(prims.relax)(C, all_src)
+                C = jnp.where(~fixed1, jnp.maximum(C, c_in), C)
         with _scope("sssp.fix"):
             fix_lb = ~fixed1 & discovered & (C >= D)
             rule_counts.append(jnp.sum(fix_lb, axis=1, dtype=jnp.int32))
             fixed2 = fixed1 | fix_lb
-        with _scope("sssp.lb"):
-            C = jnp.where(fixed2, D, C)
-        cfix_stale = fix_lb   # applied at the NEXT round's maintenance
     else:
         rule_counts.append(jnp.zeros((B,), jnp.int32))
         fixed2 = fixed1
-        with _scope("sssp.lb"):
-            C = jnp.where(fixed2, D, C)
-        c_fix = state.c_fix
+    with _scope("sssp.lb"):
+        C = jnp.where(fixed2, D, C)
 
     # --- incremental inWeight_nf refresh (restores the invariant for
     # the next round's round-start fixed = fixed2) --------------------
@@ -879,7 +831,7 @@ def _round_shared(g: Graph, cfg: SSSPConfig, state: SSSPState,
             round=state.round + 1,
             fixed_by=state.fixed_by + jnp.stack(rule_counts, axis=-1),
             f_idx=None, f_cnt=None, edges=edges,
-            in_w_nf=in_w_nf, c_fix=c_fix, cfix_stale=cfix_stale)
+            in_w_nf=in_w_nf)
     return new_state, fresh
 
 
@@ -889,24 +841,17 @@ def _attach_carries(g: Graph, cfg: SSSPConfig, prims, state: SSSPState):
     ONCE per solve, outside the round loop, which is why the hot-region
     dense-pass budgets don't see them."""
     B = state.D.shape[0]
-    need_inw = (("in" in cfg.rules) or ("pred" in cfg.rules)
-                or ("lb" in cfg.rules))
-    use_lb = "lb" in cfg.rules
+    need_inw = ("in" in cfg.rules) or ("pred" in cfg.rules)
     with _scope("sssp.init"):
         in_w_nf = (jax.vmap(prims.in_weight_nf)(~state.fixed) if need_inw
                    else None)
-        c_fix = (jax.vmap(prims.relax)(state.D, state.fixed) if use_lb
-                 else None)
-        cfix_stale = jnp.zeros_like(state.fixed) if use_lb else None
         return dataclasses.replace(
             state, f_idx=None, f_cnt=None,
-            edges=jnp.zeros((B,), jnp.int32),
-            in_w_nf=in_w_nf, c_fix=c_fix, cfix_stale=cfix_stale)
+            edges=jnp.zeros((B,), jnp.int32), in_w_nf=in_w_nf)
 
 
 def _strip_carries(state: SSSPState) -> SSSPState:
-    return dataclasses.replace(state, in_w_nf=None, c_fix=None,
-                               cfix_stale=None)
+    return dataclasses.replace(state, in_w_nf=None)
 
 
 def _frontier_fixpoint(g: Graph, cfg: SSSPConfig, prims,

@@ -135,8 +135,8 @@ def out_nbrs(csr: CsrGraph, f_idx: jax.Array) -> jax.Array:
 
     ``f_idx`` int32[cap] compacted vertex buffer (padding ``n``); padding
     cells of the result carry ``n`` (so a scatter with ``mode="drop"``
-    ignores them).  This is the shared cone-target table of one chunk of
-    the incremental inWeight_nf / c_fix / C-propagation maintenance.
+    ignores them).  This is the shared target table of one chunk of the
+    incremental inWeight_nf refresh.
     """
     n = csr.n
     u = jnp.minimum(f_idx, n - 1)
@@ -160,9 +160,9 @@ def in_min_at(g: Graph, csr: CsrGraph, x: jax.Array | None,
       x:        float32[B, n] per-lane vertex values, or None (reduce
                 the edge weight alone — the inWeight_nf recompute).
       tgt:      int32[...] target ids, SHARED across lanes (padding n).
-      src_mask: bool[B, n] per-lane source mask, or None (all sources —
-                the Eqn-(1) recompute).  At least one of ``x`` /
-                ``src_mask`` must be batched.
+      src_mask: bool[B, n] per-lane source mask, or None (all
+                sources).  At least one of ``x`` / ``src_mask`` must be
+                batched.
 
     Returns float32[B, *tgt.shape]: min over in-edges (u, t, w) with u
     masked of ``x[u] + w`` (or ``w``), +inf where nothing qualifies —

@@ -196,6 +196,45 @@ def test_incremental_in_weight_nf_matches_dense_recompute():
         f_idx, f_cnt = _compact_frontier(jnp.any(fresh, axis=0), 32, g.n)
 
 
+@pytest.mark.parametrize("cfg", [SP4_CONFIG, SSSPConfig()],
+                         ids=["label_correcting", "label_setting"])
+@pytest.mark.parametrize("family", ["grid", "geometric"])
+def test_round_shared_matches_dense_round_every_round(family, cfg):
+    """The shared-frontier round and the dense round, stepped from the
+    same state, agree bitwise on D, C and ``fixed`` after EVERY round:
+    the dense Eqn-(1) sweep of ``_round_shared`` is the line ``_round``
+    runs, so C cannot drift even for a round or two."""
+    import jax
+    from repro.core.sssp import backends
+    from repro.core.sssp.engine import (_attach_carries, _compact_frontier,
+                                        _init_state, _round, _round_shared)
+    cap = 16
+    g = _graph(family, n=120, seed=7).to_device()
+    prims = backends.frontier_prims(g, g.csr(), cap=cap)
+    seg = backends.segment_prims(g)
+    sources = jnp.asarray([0, 11], jnp.int32)
+    state = jax.vmap(lambda s: _init_state(g, s))(sources)
+    shared = _attach_carries(g, cfg, prims, state)
+    f_idx, f_cnt = _compact_frontier(
+        jnp.zeros((g.n,), bool).at[sources].set(True), cap, g.n)
+    step_shared = jax.jit(
+        lambda st, fi, fc: _round_shared(g, cfg, st, fi, fc, prims))
+    step_dense = jax.jit(jax.vmap(lambda st: _round(g, cfg, st, seg)))
+    for _ in range(g.n + 2):
+        shared, fresh = step_shared(shared, f_idx, f_cnt)
+        state = step_dense(state)
+        for leaf in ("D", "C", "fixed"):
+            assert _bitwise(getattr(shared, leaf), getattr(state, leaf)), (
+                leaf, int(state.round[0]))
+        if bool(jnp.all(state.fixed | (state.D == jnp.inf))
+                & jnp.all(state.explored == state.fixed)):
+            break
+        f_idx, f_cnt = _compact_frontier(jnp.any(fresh, axis=0), cap, g.n)
+    else:
+        pytest.fail("lanes never settled")
+    assert _bitwise(shared.fixed_by, state.fixed_by)
+
+
 def test_batched_union_overflow_falls_back_dense():
     hg = _graph("gnp", n=160, seed=4)   # union blows past cap=2 fast
     g = hg.to_device()
