@@ -43,8 +43,7 @@ TRACED_SCOPES: dict[str, tuple[str, ...]] = {
         "_round", "_cond", "_body", "_init_state", "_init_state_warm",
         "_solve", "_solve_warm", "_compact_frontier",
         "delta_taint_seeds", "delta_decrease_sources",
-        "_round_shared", "_chunked_apply", "_frontier_fixpoint",
-        "_attach_carries", "_strip_carries", "_warm_seed_mask",
+        "_round_shared", "_frontier_fixpoint", "_warm_seed_mask",
         "_solve_frontier", "_solve_warm_frontier",
     ),
     "src/repro/core/sssp/backends.py": (

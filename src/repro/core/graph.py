@@ -232,9 +232,8 @@ class CsrGraph:
     contiguous run ``g.src/g.w[in_indptr[v] : in_indptr[v+1]]``.  No
     second copy of the weights is needed — the CSC gathers read the
     primary arrays, which ``Graph.apply_delta`` keeps current, so the
-    view is GraphDelta-coherent for free.  ``max_in_deg`` bounds the
-    per-vertex in-gather width for the incremental ``inWeight_nf`` and
-    cone C-propagation recomputes.
+    view is GraphDelta-coherent for free.  ``max_in_deg`` is the
+    longest such run.
 
     Registered as a pytree (sizes static) so it rides through jit /
     ``lax.while_loop`` as a traced operand like ``Graph``/``EllGraph``.

@@ -67,12 +67,6 @@ class Primitives:
     relax_frontier_b: Callable | None = None  # (x[B,n], f_idx[cap],
     #   src_mask[B,n]) -> [B,n]: ONE shared gather of the union
     #   frontier's out-edges, per-lane scatter-min.
-    out_nbrs: Callable | None = None  # (idx[cap]) -> int32[cap, max_out]
-    #   shared target table of one inWeight_nf refresh chunk (padding n).
-    in_min_at: Callable | None = None  # (x[B,n]|None, tgt, mask[B,n]|None)
-    #   -> [B, *tgt.shape]: full in-neighbourhood masked min per target
-    #   over the CSC view — the incremental inWeight_nf recompute
-    #   primitive.
 
 
 def _masked_min_local(x: jax.Array, mask: jax.Array) -> jax.Array:
@@ -154,19 +148,20 @@ def ell_prims(g: Graph, ell: EllGraph, use_pallas: bool) -> Primitives:
     "backend.frontier",
     routes=("frontier.*",),
     require=("cumsum", "scatter-min"),
-    dense_budget={"frontier.cold": 4, "frontier.targeted": 4,
-                  "frontier.batched": 4, "frontier.warm": 6},
+    dense_budget={"frontier.cold": 6, "frontier.targeted": 6,
+                  "frontier.batched": 6, "frontier.warm": 8},
     notes="The whole point of this backend is the compacted sparse "
           "relax: the program must contain the cumsum frontier "
           "compaction AND the scatter-min relax — on EVERY route, "
           "batched and warm included (the shared batch frontier of "
           "engine._round_shared; the old dense-under-vmap waiver is "
           "retired).  The budgets count the step-1 dense-relax "
-          "fallback branch (2), the Eqn-(1) C-propagation sweep (2: "
-          "after the Lemma-7 lift nearly every unfixed vertex's C can "
-          "move, so a wavefront-bounded walk covers more slots than "
-          "one dense sweep) and the warm taint sweep (2).  inWeight_nf "
-          "stays an incremental chunked update with no dense rebuild "
+          "fallback branch (2), the round-start inWeight_nf sweep (2), "
+          "the Eqn-(1) C-propagation sweep (2: after the Lemma-7 lift "
+          "nearly every unfixed vertex's C can move, so a "
+          "wavefront-bounded walk covers more slots than one dense "
+          "sweep) and the warm taint sweep (2): the segment backend's "
+          "budgets, with only the step-1 relax sparse "
           "(docs/round-anatomy.md).")
 def frontier_prims(g: Graph, csr: CsrGraph, cap: int,
                    use_pallas: bool = False) -> Primitives:
@@ -176,13 +171,11 @@ def frontier_prims(g: Graph, csr: CsrGraph, cap: int,
     ``cap``) buffered vertices — ``cap * csr.max_out_deg`` edge slots
     instead of ``e_pad`` — through the Pallas scatter-min kernel
     (kernels/frontier_relax) when ``use_pallas``, the jnp oracle
-    otherwise.  The batched hooks (``relax_frontier_b`` / ``out_nbrs``
-    / ``in_min_at``) switch the engine to ``_round_shared``: one UNION
-    frontier per batch and incremental inWeight_nf over the CSC run
-    table.  The dense segment primitives remain as the step-1 overflow
-    fallback, the Eqn-(1) C-propagation sweep and the init-region
-    seeds, which keeps every round bitwise-identical to the segment
-    backend.
+    otherwise.  The batched hook ``relax_frontier_b`` switches the
+    engine to ``_round_shared``: one UNION frontier per batch.  The
+    dense segment primitives remain as the step-1 overflow fallback,
+    the inWeight_nf and Eqn-(1) sweeps, which keeps every round
+    bitwise-identical to the segment backend.
     """
     from repro.kernels import ops
 
@@ -196,18 +189,11 @@ def frontier_prims(g: Graph, csr: CsrGraph, cap: int,
         return ops.frontier_relax_b(x, csr, f_idx, src_mask,
                                     use_pallas=use_pallas)
 
-    def out_nbrs(idx):
-        return ops.out_nbrs(csr, idx)
-
-    def in_min_at(x, tgt, src_mask):
-        return ops.in_min_at(g, csr, x, tgt, src_mask)
-
     return Primitives(relax=base.relax, in_weight_nf=base.in_weight_nf,
                       masked_min=_masked_min_local,
                       relax_frontier=relax_frontier,
                       frontier_cap=int(cap),
-                      relax_frontier_b=relax_frontier_b,
-                      out_nbrs=out_nbrs, in_min_at=in_min_at)
+                      relax_frontier_b=relax_frontier_b)
 
 
 @contract(

@@ -42,7 +42,7 @@ profiler trace can sum device time by pass (docs/round-anatomy.md):
 
   sssp.relax     step-1 D relaxation (dense fallback and ``relax2``)
   sssp.lb        step 3: Lemma-7 lift and the Eqn-(1) sweep
-  sssp.inw       inWeight_nf, dense or the incremental refresh
+  sssp.inw       the inWeight_nf sweep
   sssp.fix       step-2 reductions and every fixing/un-fixing rule
   sssp.frontier  next-round fresh mask and its compaction
   sssp.freeze    loop predicates, lane liveness and select-freeze
@@ -122,12 +122,6 @@ class SSSPState:
     #   The physical gather of a sparse round touches up to
     #   cap * max_out_deg padded slots regardless of how many are live —
     #   the bench reports that bound separately (slot_ratio).
-    # --- shared-batch-frontier carries (engine-internal state of
-    # ``_round_shared``; None on every other path) ---
-    in_w_nf: jax.Array | None = None  # float32[B, n] incremental
-    #   inWeight_nf: min in-edge weight over NON-fixed sources, valid for
-    #   this round-start ``fixed``; refreshed end-of-round only at the
-    #   out-neighbourhoods of vertices whose fixed bit flipped.
 
 
 @dataclasses.dataclass
@@ -413,8 +407,8 @@ def _solve_warm(g: Graph, cfg: SSSPConfig, prev_D, prev_fixed, seeds,
 
     Batch-capable frontier ``prims`` (``relax_frontier_b`` set) route to
     the shared-frontier driver at B=1 — warm rounds then run the same
-    sparse round body (incremental inWeight_nf, cone C-propagation) as
-    warm *batches* do, instead of the dense body.
+    shared-frontier round body as warm *batches* do, instead of the
+    dense body.
     """
     if getattr(prims, "relax_frontier_b", None) is not None:
         st, sweeps, taint = _solve_warm_frontier(
@@ -481,7 +475,7 @@ def _round(g: Graph, cfg: SSSPConfig, state: SSSPState,
     the round directly over their own lanes (bidirectional.py's two-lane
     program, whose ``cap >= n`` keeps the sparse branch static).  Every
     Solver/Dynamic/Fleet frontier route instead takes ``_round_shared``
-    below, where inWeight_nf is an incremental carry too (see
+    below, which shares one union buffer across lanes (see
     docs/round-anatomy.md).
     """
     if prims is None:
@@ -646,35 +640,6 @@ def _round(g: Graph, cfg: SSSPConfig, state: SSSPState,
             f_idx=f_idx, f_cnt=f_cnt, edges=edges)
 
 
-def _chunked_apply(apply_chunk, idx: jax.Array, cnt: jax.Array, cap: int,
-                   carry):
-    """Fold ``apply_chunk(chunk int32[cap], carry) -> carry`` over
-    ``cap``-sized chunks of a full compacted index list ``idx``
-    (int32[n], padding n) until ``cnt`` entries are consumed.
-
-    This is how the incremental inWeight_nf refresh stays
-    wavefront-proportional WITHOUT a dense fallback branch: a round pays
-    ``ceil(cnt / cap)`` chunk sweeps under a ``lax.while_loop`` — never
-    a full-``e_pad`` pass.  Each chunk costs its full ``cap`` width
-    however few of its entries are live.  Chunks partition the target
-    set, and every chunk's updates are full recomputes at its targets
-    (order-independent), so chunking is bitwise-neutral.
-    """
-    n = idx.shape[0]
-    idx_pad = jnp.concatenate([idx, jnp.full((cap,), n, idx.dtype)])
-
-    def cond(c):
-        return c[0] < cnt
-
-    def body(c):
-        start, cur = c
-        chunk = jax.lax.dynamic_slice(idx_pad, (start,), (cap,))
-        return start + jnp.int32(cap), apply_chunk(chunk, cur)
-
-    _, carry = jax.lax.while_loop(cond, body, (jnp.int32(0), carry))
-    return carry
-
-
 def _round_shared(g: Graph, cfg: SSSPConfig, state: SSSPState,
                   f_idx: jax.Array, f_cnt: jax.Array,
                   prims: backends.Primitives, warm: bool = False):
@@ -693,20 +658,19 @@ def _round_shared(g: Graph, cfg: SSSPConfig, state: SSSPState,
       ``lax.cond`` branch even though the lanes are batched — the exact
       failure mode of vmapping ``_round`` (batched predicate -> select
       -> both branches every round) that this body exists to avoid.
-    * **inWeight_nf** is an incremental carry (``state.in_w_nf``): valid
-      for round-start ``fixed`` by induction, refreshed end-of-round
-      only at out-neighbours of vertices whose fixed bit flipped
-      (full in-neighbourhood recompute per target via ``prims.in_min_at``
-      — a min is order-independent, so recompute-at-a-superset is exact).
-    * **C-propagation** is one dense Eqn-(1) sweep per
-      ``c_prop_iters`` iteration, the line ``_round`` runs, vmapped over
-      lanes.  A wavefront bound buys nothing here: the Lemma-7 lift puts
-      every unfixed C at ``minD`` or above, and Eqn (1) then lifts every
-      unfixed vertex whose in-sources are all unfixed above ``minD``
-      (w > 0), so the vertices whose C can still move cover nearly the
-      whole unfixed set in every round (docs/round-anatomy.md §4).
-    * The inWeight_nf refresh runs through ``_chunked_apply``:
-      wavefront-proportional with no dense branch.
+    * **inWeight_nf** and **C-propagation** are dense sweeps, the lines
+      ``_round`` runs, vmapped over lanes: inWeight_nf once per round
+      over the round-start ``fixed``, Eqn (1) once per ``c_prop_iters``
+      iteration.  A wavefront bound buys nothing for Eqn (1): the
+      Lemma-7 lift puts every unfixed C at ``minD`` or above, and Eqn (1)
+      then lifts every unfixed vertex whose in-sources are all unfixed
+      above ``minD`` (w > 0), so the vertices whose C can still move
+      cover nearly the whole unfixed set in every round
+      (docs/round-anatomy.md §4).  Nor for inWeight_nf: a refresh walk
+      around the vertices whose fixed bit flipped gathers a
+      ``[B, cap, max_out, max_in]`` table per chunk, on a street lattice
+      as many slots as one dense sweep, at a higher price per slot
+      (docs/round-anatomy.md §5).
 
     Returns ``(state, fresh)`` with ``fresh`` the per-lane bool[B, n]
     next-round frontier mask; the driver unions it, compacts once, and
@@ -740,7 +704,10 @@ def _round_shared(g: Graph, cfg: SSSPConfig, state: SSSPState,
         edges = state.edges + jnp.where(overflow, jnp.int32(g.e_pad),
                                         sparse_edges)
 
-    in_w_nf = state.in_w_nf   # invariant: == in_weight_nf(~round-start fixed)
+    in_w_nf = None
+    if ("in" in cfg.rules) or ("pred" in cfg.rules):
+        with _scope("sssp.inw"):   # round-start fixed, before any un-fix
+            in_w_nf = jax.vmap(prims.in_weight_nf)(~fixed)
     if warm:
         with _scope("sssp.fix"):
             improved = fixed & (D_relax < D)
@@ -804,21 +771,6 @@ def _round_shared(g: Graph, cfg: SSSPConfig, state: SSSPState,
     with _scope("sssp.lb"):
         C = jnp.where(fixed2, D, C)
 
-    # --- incremental inWeight_nf refresh (restores the invariant for
-    # the next round's round-start fixed = fixed2) --------------------
-    if in_w_nf is not None:
-        with _scope("sssp.inw"):
-            stale2 = state.fixed ^ fixed2     # every bit flip this round
-            w_idx, w_cnt = _compact_frontier(
-                jnp.any(stale2, axis=0), g.n, g.n)
-
-            def inw_chunk(chunk, iw):
-                tgts = prims.out_nbrs(chunk)
-                vals = prims.in_min_at(None, tgts, ~fixed2)   # min weight
-                return iw.at[:, tgts].set(vals, mode="drop")
-
-            in_w_nf = _chunked_apply(inw_chunk, w_idx, w_cnt, cap, in_w_nf)
-
     # --- next-round frontier mask (same freshness law as ``_round``) -
     with _scope("sssp.frontier"):
         if cfg.label_correcting:
@@ -830,28 +782,8 @@ def _round_shared(g: Graph, cfg: SSSPConfig, state: SSSPState,
             D=D, C=C, fixed=fixed2, explored=explored,
             round=state.round + 1,
             fixed_by=state.fixed_by + jnp.stack(rule_counts, axis=-1),
-            f_idx=None, f_cnt=None, edges=edges,
-            in_w_nf=in_w_nf)
+            f_idx=None, f_cnt=None, edges=edges)
     return new_state, fresh
-
-
-def _attach_carries(g: Graph, cfg: SSSPConfig, prims, state: SSSPState):
-    """Seed the shared-frontier round carries onto a freshly-initialized
-    ``[B, n]`` state.  These are init-region dense reductions — they run
-    ONCE per solve, outside the round loop, which is why the hot-region
-    dense-pass budgets don't see them."""
-    B = state.D.shape[0]
-    need_inw = ("in" in cfg.rules) or ("pred" in cfg.rules)
-    with _scope("sssp.init"):
-        in_w_nf = (jax.vmap(prims.in_weight_nf)(~state.fixed) if need_inw
-                   else None)
-        return dataclasses.replace(
-            state, f_idx=None, f_cnt=None,
-            edges=jnp.zeros((B,), jnp.int32), in_w_nf=in_w_nf)
-
-
-def _strip_carries(state: SSSPState) -> SSSPState:
-    return dataclasses.replace(state, in_w_nf=None)
 
 
 def _frontier_fixpoint(g: Graph, cfg: SSSPConfig, prims,
@@ -915,25 +847,24 @@ def _solve_frontier(g: Graph, cfg: SSSPConfig, sources: jax.Array,
 
     ``sources`` int32[B]; ``C0`` float32[B, n] or None; ``targets``
     int32[B] (sentinel -1 = untargeted lane) or None.  Returns a state
-    with [B, ...] leaves, engine-internal carries stripped.  The initial
-    buffer is the union of the lane sources — label-setting round 1
-    relaxes nothing, and label-correcting lanes mask foreign sources out
-    via ``relax_src``, so the union seed is bitwise-neutral.
+    with [B, ...] leaves.  The initial buffer is the union of the lane
+    sources — label-setting round 1 relaxes nothing, and
+    label-correcting lanes mask foreign sources out via ``relax_src``,
+    so the union seed is bitwise-neutral.
     """
     cap = prims.frontier_cap
     if C0 is None:
         state = jax.vmap(lambda s: _init_state(g, s))(sources)
     else:
         state = jax.vmap(lambda s, c: _init_state(g, s, c))(sources, C0)
-    state = _attach_carries(g, cfg, prims, state)
     with _scope("sssp.init"):
+        state = dataclasses.replace(state, edges=jnp.zeros_like(state.round))
         src_mask = jnp.zeros((g.n,), bool).at[sources].set(True)
         f_idx, f_cnt = _compact_frontier(src_mask, cap, g.n)
     max_rounds = cfg.max_rounds or g.n + 2
     tgt = targets if cfg.early_exit else None
-    state = _frontier_fixpoint(g, cfg, prims, state, f_idx, f_cnt,
-                               max_rounds, targets=tgt)
-    return _strip_carries(state)
+    return _frontier_fixpoint(g, cfg, prims, state, f_idx, f_cnt,
+                              max_rounds, targets=tgt)
 
 
 def _solve_warm_frontier(g: Graph, cfg: SSSPConfig, prev_D, prev_fixed,
@@ -956,7 +887,7 @@ def _solve_warm_frontier(g: Graph, cfg: SSSPConfig, prev_D, prev_fixed,
 
     state, sweeps, taint = jax.vmap(init_one)(
         prev_D, prev_fixed, seeds, pure_increase)
-    state = _attach_carries(g, cfg, prims, state)
+    state = dataclasses.replace(state, edges=jnp.zeros_like(state.round))
     with _scope("sssp.taint"):
         seed = jax.vmap(
             lambda t, f, d: _warm_seed_mask(g, t, f, d, dec_src))(
@@ -965,7 +896,7 @@ def _solve_warm_frontier(g: Graph, cfg: SSSPConfig, prev_D, prev_fixed,
     max_rounds = (2 * cfg.max_rounds) if cfg.max_rounds else 2 * g.n + 4
     state = _frontier_fixpoint(g, cfg, prims, state, f_idx, f_cnt,
                                max_rounds, warm=True)
-    return _strip_carries(state), sweeps, taint
+    return state, sweeps, taint
 
 
 def _cond(state: SSSPState, max_rounds: int, target=None):
@@ -999,8 +930,7 @@ def _solve(g: Graph, cfg: SSSPConfig, source,
 
     Batch-capable frontier ``prims`` (``relax_frontier_b`` set) route to
     the shared-frontier driver at B=1: single solves then run the very
-    round body batches run — incremental inWeight_nf, cone-bounded
-    C-propagation — not just the sparse relax."""
+    round body batches run."""
     if getattr(prims, "relax_frontier_b", None) is not None:
         src = jnp.asarray(source, jnp.int32).reshape((1,))
         c0 = None if C0 is None else C0.reshape((1, -1))

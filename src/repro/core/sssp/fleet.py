@@ -307,15 +307,15 @@ class FleetBatchResult:
     "fleet.frontier",
     routes=("fleet_frontier.*",),
     require=("cumsum", "scatter-min"),
-    dense_budget={"fleet_frontier.warm": 12, "fleet_frontier.*": 8},
+    dense_budget={"fleet_frontier.warm": 16, "fleet_frontier.*": 12},
     notes="backend='frontier' python-unrolls the members through the "
           "shared-batch-frontier round body — the compiled program "
           "must contain each member's cumsum union compaction and "
           "scatter-min relax, and the dense budget is PER PROGRAM "
           "(F x the solo frontier budget at the probe's F=2): only "
-          "each member's step-1 overflow-fallback branch, Eqn-(1) "
-          "C-propagation sweep and warm taint sweep may touch e_pad "
-          "(docs/round-anatomy.md).")
+          "each member's step-1 overflow-fallback branch, inWeight_nf "
+          "sweep, Eqn-(1) C-propagation sweep and warm taint sweep may "
+          "touch e_pad (docs/round-anatomy.md).")
 class FleetSolver:
     """Compiled SSSP over a whole :class:`GraphFleet`.
 

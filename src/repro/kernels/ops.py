@@ -16,7 +16,6 @@ import jax.numpy as jnp
 
 from repro.core.graph import CsrGraph, EllGraph
 from repro.kernels import ref
-from repro.core.graph import Graph
 from repro.kernels.frontier_relax import (
     frontier_scatter_min as _frontier_scatter_pallas,
     frontier_scatter_min_batch as _frontier_scatter_batch_pallas)
@@ -128,62 +127,6 @@ def frontier_relax_b(x: jax.Array, csr: CsrGraph, f_idx: jax.Array,
         return _frontier_scatter_batch_pallas(tgt, cand, n,
                                               interpret=_interpret())
     return ref.frontier_scatter_min_batch_ref(tgt, cand, n)
-
-
-def out_nbrs(csr: CsrGraph, f_idx: jax.Array) -> jax.Array:
-    """int32[cap, max_out] out-neighbour ids of the buffered vertices.
-
-    ``f_idx`` int32[cap] compacted vertex buffer (padding ``n``); padding
-    cells of the result carry ``n`` (so a scatter with ``mode="drop"``
-    ignores them).  This is the shared target table of one chunk of the
-    incremental inWeight_nf refresh.
-    """
-    n = csr.n
-    u = jnp.minimum(f_idx, n - 1)
-    base = csr.indptr[u]
-    deg = csr.indptr[u + 1] - base
-    j = jnp.arange(csr.max_out_deg, dtype=jnp.int32)[None, :]
-    cell = (f_idx < n)[:, None] & (j < deg[:, None])
-    epos = jnp.minimum(base[:, None] + j, csr.e_pad - 1)
-    return jnp.where(cell, csr.dst[epos], n)
-
-
-def in_min_at(g: Graph, csr: CsrGraph, x: jax.Array | None,
-              tgt: jax.Array, src_mask: jax.Array | None) -> jax.Array:
-    """Masked min over the FULL in-neighbourhood of each target vertex.
-
-    The CSC run table (``csr.in_indptr``) points into the primary
-    dst-sorted ``g.src``/``g.w`` arrays, so in-edges of vertex t are the
-    contiguous slots ``in_indptr[t]:in_indptr[t+1]`` — delta-coherent
-    for free (GraphDelta rewrites ``g.w`` in place).
-
-      x:        float32[B, n] per-lane vertex values, or None (reduce
-                the edge weight alone — the inWeight_nf recompute).
-      tgt:      int32[...] target ids, SHARED across lanes (padding n).
-      src_mask: bool[B, n] per-lane source mask, or None (all
-                sources).  At least one of ``x`` / ``src_mask`` must be
-                batched.
-
-    Returns float32[B, *tgt.shape]: min over in-edges (u, t, w) with u
-    masked of ``x[u] + w`` (or ``w``), +inf where nothing qualifies —
-    exactly the per-target slice of the dense reduction, so recomputing
-    at any superset of stale targets is bitwise-neutral.
-    """
-    n = g.n
-    tc = jnp.minimum(tgt, n - 1)
-    base = csr.in_indptr[tc]
-    deg = csr.in_indptr[tc + 1] - base
-    j = jnp.arange(csr.max_in_deg, dtype=jnp.int32)
-    cell = (tgt < n)[..., None] & (j < deg[..., None])
-    epos = jnp.minimum(base[..., None] + j, g.e_pad - 1)
-    u_raw = g.src[epos]
-    uc = jnp.minimum(u_raw, n - 1)
-    ok = cell & (u_raw < n)
-    w = jnp.where(ok, g.w[epos], jnp.inf)      # [*T, max_in]
-    if src_mask is not None:                   # masked sources offer +inf
-        x = jnp.where(src_mask, 0.0 if x is None else x, jnp.inf)
-    val = w[None] if x is None else x[:, uc] + w[None]
-    return jnp.min(val, axis=-1)
 
 
 def masked_min(x: jax.Array, mask: jax.Array,

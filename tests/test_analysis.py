@@ -82,6 +82,28 @@ def test_frontier_routes_pass_hard_with_no_waivers():
         assert not v.violations
 
 
+@pytest.fixture(scope="module")
+def frontier_verdicts():
+    from repro.analysis.routes import build_routes
+    routes = build_routes(include=("frontier.*", "fleet_frontier.*"))
+    return {name: lint_route(name, r.jaxpr, dense_dims=r.dense_dims)
+            for name, r in routes.items()}
+
+
+@pytest.mark.parametrize("route", [
+    "frontier.cold", "frontier.targeted", "frontier.batched",
+    "frontier.warm", "fleet_frontier.cold", "fleet_frontier.batched",
+    "fleet_frontier.warm"])
+def test_frontier_dense_passes_equal_budget(frontier_verdicts, route):
+    """Each frontier route's budget is exactly what it runs: the step-1
+    fallback, the inWeight_nf and Eqn-(1) sweeps (and the warm taint
+    sweep), per member in a fleet.  A sweep added or taken away must
+    move the budget with it."""
+    v = frontier_verdicts[route]
+    assert v.dense_budget is not None
+    assert v.dense_passes == v.dense_budget, (route, v.dense_passes)
+
+
 # ---------------------------------------------------------------------------
 # jaxpr_lint mechanics
 # ---------------------------------------------------------------------------

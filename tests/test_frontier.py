@@ -172,57 +172,79 @@ def test_batched_bitwise_vs_segment(family):
         assert int(bf.rounds[i]) == solo.rounds, family
 
 
-def test_incremental_in_weight_nf_matches_dense_recompute():
-    """The carried ``in_w_nf`` (updated only over in-neighbourhoods of
-    flipped-bit vertices) must equal the dense full-graph reduction
-    after EVERY round — the invariant docs/round-anatomy.md states."""
+_ROUND_CASES = [
+    pytest.param(family, cfg, warm,
+                 id="-".join([family, cid] + (["warm"] if warm else [])))
+    for warm in (False, True)
+    for family in ("grid", "geometric")
+    for cfg, cid in ((SP4_CONFIG, "label_correcting"),
+                     (SSSPConfig(), "label_setting"))]
+
+
+def _warm_start(g, cfg, sources):
+    """Lanes warm-started after a weight DECREASE on the solved graph:
+    ``(g2, csr2, state, seed_mask)`` as ``_solve_warm_frontier`` builds
+    them, so warm rounds un-fix stale-high fixed vertices."""
     import jax
-    from repro.core.sssp import backends
-    from repro.core.sssp.engine import (_attach_carries, _compact_frontier,
-                                        _init_state, _round_shared)
-    hg = _graph("geometric", n=120, seed=7)
-    g = hg.to_device()
-    prims = backends.frontier_prims(g, g.csr(), cap=32)
-    sources = jnp.asarray([0, 11], jnp.int32)
-    state = jax.vmap(lambda s: _init_state(g, s))(sources)
-    state = _attach_carries(g, SP4_CONFIG, prims, state)
-    src_mask = jnp.zeros((g.n,), bool).at[sources].set(True)
-    f_idx, f_cnt = _compact_frontier(src_mask, 32, g.n)
-    for _ in range(12):
-        state, fresh = _round_shared(g, SP4_CONFIG, state, f_idx, f_cnt,
-                                     prims)
-        want = jax.vmap(prims.in_weight_nf)(~state.fixed)
-        assert _bitwise(state.in_w_nf, want)
-        f_idx, f_cnt = _compact_frontier(jnp.any(fresh, axis=0), 32, g.n)
+    from repro.core.sssp.dynamic import make_delta
+    from repro.core.sssp.engine import (_init_state_warm, _warm_seed_mask,
+                                        delta_decrease_sources,
+                                        delta_taint_seeds)
+    prev = Solver(g, cfg, backend="segment").solve_batch(
+        [int(s) for s in sources])
+    idx = np.arange(0, g.e, max(1, g.e // 12))[:12]
+    delta = make_delta(g, idx, (np.asarray(g.w)[idx] * 0.25).astype(
+        np.float32))
+    g2, csr2 = g.apply_delta(delta), g.csr().apply_delta(delta)
+    prev_D, prev_fixed = jnp.asarray(prev.dist), jnp.asarray(prev.fixed)
+    seeds, pure = jax.vmap(lambda d: delta_taint_seeds(g, delta, d))(prev_D)
+    dec = delta_decrease_sources(g, delta)
+    state, _, taint = jax.vmap(
+        lambda d, f, sd, pu: _init_state_warm(g2, d, f, sd, pu))(
+            prev_D, prev_fixed, seeds, pure)
+    seed = jax.vmap(lambda t, f, d: _warm_seed_mask(g2, t, f, d, dec))(
+        taint, state.fixed, state.D)
+    return g2, csr2, state, jnp.any(seed, axis=0)
 
 
-@pytest.mark.parametrize("cfg", [SP4_CONFIG, SSSPConfig()],
-                         ids=["label_correcting", "label_setting"])
-@pytest.mark.parametrize("family", ["grid", "geometric"])
-def test_round_shared_matches_dense_round_every_round(family, cfg):
+@pytest.mark.parametrize("family, cfg, warm", _ROUND_CASES)
+def test_round_shared_matches_dense_round_every_round(family, cfg, warm):
     """The shared-frontier round and the dense round, stepped from the
     same state, agree bitwise on D, C and ``fixed`` after EVERY round:
-    the dense Eqn-(1) sweep of ``_round_shared`` is the line ``_round``
-    runs, so C cannot drift even for a round or two."""
+    the dense inWeight_nf and Eqn-(1) sweeps of ``_round_shared`` are
+    the lines ``_round`` runs, so neither can drift even for a round or
+    two.  The warm cases start from a warm state after a weight
+    decrease, so rounds un-fix vertices and inWeight_nf must read the
+    round-start ``fixed``, before the un-fix."""
     import jax
     from repro.core.sssp import backends
-    from repro.core.sssp.engine import (_attach_carries, _compact_frontier,
-                                        _init_state, _round, _round_shared)
+    from repro.core.sssp.engine import (_compact_frontier, _init_state,
+                                        _round, _round_shared)
     cap = 16
     g = _graph(family, n=120, seed=7).to_device()
-    prims = backends.frontier_prims(g, g.csr(), cap=cap)
-    seg = backends.segment_prims(g)
     sources = jnp.asarray([0, 11], jnp.int32)
-    state = jax.vmap(lambda s: _init_state(g, s))(sources)
-    shared = _attach_carries(g, cfg, prims, state)
-    f_idx, f_cnt = _compact_frontier(
-        jnp.zeros((g.n,), bool).at[sources].set(True), cap, g.n)
+    if warm:
+        g, csr, state, seed = _warm_start(g, cfg, sources)
+    else:
+        csr = g.csr()
+        state = jax.vmap(lambda s: _init_state(g, s))(sources)
+        seed = jnp.zeros((g.n,), bool).at[sources].set(True)
+    prims = backends.frontier_prims(g, csr, cap=cap)
+    seg = backends.segment_prims(g)
+    shared = dataclasses.replace(state, edges=jnp.zeros_like(state.round))
+    f_idx, f_cnt = _compact_frontier(seed, cap, g.n)
     step_shared = jax.jit(
-        lambda st, fi, fc: _round_shared(g, cfg, st, fi, fc, prims))
-    step_dense = jax.jit(jax.vmap(lambda st: _round(g, cfg, st, seg)))
-    for _ in range(g.n + 2):
+        lambda st, fi, fc: _round_shared(g, cfg, st, fi, fc, prims,
+                                         warm=warm))
+    step_dense = jax.jit(jax.vmap(
+        lambda st: _round(g, cfg, st, seg, warm=warm)))
+    unfixed = 0
+    for _ in range(2 * g.n + 4):
         shared, fresh = step_shared(shared, f_idx, f_cnt)
+        before = state
         state = step_dense(state)
+        # a fixed vertex's D moves only if this round un-fixed it
+        unfixed += int(jnp.sum(before.fixed & (state.D < before.D)))
         for leaf in ("D", "C", "fixed"):
             assert _bitwise(getattr(shared, leaf), getattr(state, leaf)), (
                 leaf, int(state.round[0]))
@@ -233,6 +255,7 @@ def test_round_shared_matches_dense_round_every_round(family, cfg):
     else:
         pytest.fail("lanes never settled")
     assert _bitwise(shared.fixed_by, state.fixed_by)
+    assert (unfixed > 0) == warm   # the warm cases reach the un-fix path
 
 
 def test_batched_union_overflow_falls_back_dense():

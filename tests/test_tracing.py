@@ -63,14 +63,43 @@ def test_solve_batch_carries_every_round_scope(backend, absent):
     assert _scopes(hlo) == SCOPES - absent
 
 
-@pytest.mark.parametrize("backend,absent", [
-    ("segment", {"sssp.frontier", "sssp.init"}),  # the state is the old one
-    ("frontier", set()),
+@pytest.mark.parametrize("backend,absent", [   # the state is the old one
+    ("segment", {"sssp.frontier", "sssp.init"}),
+    ("frontier", {"sssp.init"}),
 ])
 def test_warm_program_carries_every_scope(backend, absent):
     s = DynamicSolver(_grid(), backend=backend)
     hlo = s._jit_warm.lower(*_warm_args(s)).compile().as_text()
     assert _scopes(hlo) == SCOPES - absent
+
+
+def _loop_scopes(jaxpr, prefix=""):
+    """The name stack of every ``while`` equation, nested ones included
+    (an inner stack is relative to the equation that holds it)."""
+    from repro.analysis.jaxpr_lint import _sub_jaxprs
+    out = []
+    for eqn in jaxpr.eqns:
+        stack = f"{prefix}/{eqn.source_info.name_stack}"
+        if eqn.primitive.name == "while":
+            out.append(stack)
+        for sub in _sub_jaxprs(eqn):
+            out += _loop_scopes(sub, stack)
+    return out
+
+
+@pytest.mark.parametrize("program", ["batch", "warm"])
+def test_frontier_inw_pass_runs_no_loop(program):
+    """inWeight_nf is one dense sweep per round on the frontier backend:
+    the round loop holds no ``while`` of its own in ``sssp.inw``."""
+    if program == "batch":
+        s = Solver(_grid(), backend="frontier")
+        jaxpr = jax.make_jaxpr(s._jit_batch)(*_batch_args(s))
+    else:
+        s = DynamicSolver(_grid(), backend="frontier")
+        jaxpr = jax.make_jaxpr(s._warm_program)(*_warm_args(s))
+    loops = _loop_scopes(jaxpr.jaxpr)
+    assert loops   # the round loop itself
+    assert not [x for x in loops if "sssp.inw" in x], loops
 
 
 @contextlib.contextmanager
